@@ -936,11 +936,16 @@ def main() -> None:
                          "JSONL + metrics snapshot under artifacts/obs/")
     args = ap.parse_args()
     if args.sharded:
-        # must precede the first jax import (bench functions import lazily)
+        # the 8-node mesh is emulated on host devices: pin this process to
+        # the CPU so it never takes a chip. Must precede the first jax
+        # import (bench functions import lazily).
+        os.environ["JAX_PLATFORMS"] = "cpu"
         flags = os.environ.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8").strip()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     names = [args.only] if args.only else list(ALL)
     for name in names:
         print(f"\n== {name} ==")

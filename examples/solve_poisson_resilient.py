@@ -28,10 +28,12 @@ import numpy as np
 
 from repro.core.driver import solve_resilient
 from repro.core.failures import FailureEvent, SDCEvent
+from repro.launch.compile_cache import use_compile_cache
 from repro.sparse.matrices import build_problem
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--kind", default="poisson3d",
                     choices=["poisson2d", "poisson3d", "banded"])

@@ -114,9 +114,10 @@ def _broken_sharding():
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from jax.sharding import AxisType
 
-    mesh = jax.make_mesh((1,), ("nodes",))
+    mesh = jax.make_mesh((1,), ("nodes",), axis_types=(AxisType.Auto,))
 
     def runner(x, xb):                  # x: (M,), xb: (B, M)
         # out_specs P() replicates the whole vector on every device;
@@ -124,10 +125,10 @@ def _broken_sharding():
         rep = shard_map(lambda v: jax.lax.all_gather(v, "nodes",
                                                      tiled=True),
                         mesh=mesh, in_specs=(P("nodes"),), out_specs=P(),
-                        check_rep=False)(x)
+                        check_vma=False)(x)
         mixed = shard_map(lambda v: v * 2.0, mesh=mesh,
                           in_specs=(P("nodes"),), out_specs=P("nodes"),
-                          check_rep=False)(xb)
+                          check_vma=False)(xb)
         return rep, mixed
 
     jaxpr = jax.make_jaxpr(runner)(jnp.ones(512), jnp.ones((_B, 64)))

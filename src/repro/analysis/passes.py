@@ -42,7 +42,7 @@ WORK_PRIMS = frozenset({
 # primitives that force a device->host transfer (or round-trip) when they
 # appear inside a chunk body: the sync-free driver protocol forbids them
 SYNC_PRIM_NAMES = frozenset({
-    "infeed", "outfeed", "host_local_array_to_global_array",
+    "infeed", "outfeed", "debug_print", "host_local_array_to_global_array",
     "global_array_to_host_local_array",
 })
 
@@ -276,10 +276,10 @@ def _size(shape) -> int:
 # --------------------------------------------------------------------------- #
 # pass 5: sharding-spec check
 # --------------------------------------------------------------------------- #
-def _names_to_axis(names: dict, rank: int):
-    """shard_map in/out names dict {dim: (axis,...)} -> index of the dim
-    sharded on the mesh (None = fully replicated)."""
-    sharded = [d for d, ax in names.items() if ax]
+def _spec_to_axis(spec) -> int | None:
+    """shard_map in/out PartitionSpec -> index of the dim sharded on the
+    mesh (None = fully replicated)."""
+    sharded = [d for d, ax in enumerate(spec) if ax]
     return sharded[0] if sharded else None
 
 
@@ -297,14 +297,14 @@ def run_sharding(spec: EntrySpec) -> list[Finding]:
                 spec, "sharding", site.path, "foreign-mesh",
                 f"shard_map over mesh axes {axis_names} — entry declared "
                 f"{tuple(spec.mesh_axes)}"))
-        in_names = eqn.params.get("in_names", ())
-        out_names = eqn.params.get("out_names", ())
-        roles = [("in", n, v.aval) for n, v in zip(in_names, eqn.invars)] + \
-                [("out", n, v.aval) for n, v in zip(out_names, eqn.outvars)]
-        for role, names, aval in roles:
+        in_specs = eqn.params.get("in_specs", ())
+        out_specs = eqn.params.get("out_specs", ())
+        roles = [("in", p, v.aval) for p, v in zip(in_specs, eqn.invars)] + \
+                [("out", p, v.aval) for p, v in zip(out_specs, eqn.outvars)]
+        for role, pspec, aval in roles:
             shape = tuple(getattr(aval, "shape", ()))
             rank = len(shape)
-            axis = _names_to_axis(names, rank)
+            axis = _spec_to_axis(pspec)
             if axis is None:
                 if _size(shape) > spec.repl_limit:
                     findings.append(_f(

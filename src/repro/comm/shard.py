@@ -38,13 +38,15 @@ Three layers:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis.marks import sync_free
 from repro.sparse.blockell import BlockEll
@@ -52,7 +54,8 @@ from repro.sparse.matrices import Problem
 
 
 def nodes_mesh(n_nodes: int) -> Mesh:
-    return jax.make_mesh((n_nodes,), ("nodes",))
+    return jax.make_mesh((n_nodes,), ("nodes",),
+                         axis_types=(AxisType.Auto,))
 
 
 def place_problem(problem: Problem, mesh: Mesh) -> Problem:
@@ -63,7 +66,6 @@ def place_problem(problem: Problem, mesh: Mesh) -> Problem:
     a2 = BlockEll(jax.device_put(a.data, row_sh),
                   jax.device_put(a.idx, row_sh),
                   jax.device_put(a.nblk, row_sh), a.shape, a.bm, a.bn)
-    import dataclasses
     return dataclasses.replace(
         problem, a=a2, b=jax.device_put(problem.b, vec_sh),
         pinv_blocks=jax.device_put(problem.pinv_blocks, row_sh),
@@ -88,29 +90,35 @@ def sharded_matvec(a: BlockEll, mesh: Mesh, batch: int = 0):
     device runs the per-member-unrolled sequential product over its slab —
     per member bit-identical to the unbatched sharded product.
     """
+    mv = _sharded_spmv(mesh, batch)
+    return lambda x: mv(a.data, a.idx, x)
+
+
+def _sharded_spmv(mesh: Mesh, batch: int = 0):
+    """``sharded_matvec``'s product as (data, idx, x) -> y."""
     from repro.kernels.spmv.ref import spmv_seq_ref
 
     if batch:
         @functools.partial(
             shard_map, mesh=mesh,
             in_specs=(P("nodes"), P("nodes"), P(None, "nodes")),
-            out_specs=P(None, "nodes"), check_rep=False)
+            out_specs=P(None, "nodes"), check_vma=False)
         def mv_b(data, idx, x_local):
             xg = jax.lax.all_gather(x_local, "nodes", axis=1, tiled=True)
             return jnp.stack([spmv_seq_ref(data, idx, xg[i])
                               for i in range(batch)])
 
-        return lambda x: mv_b(a.data, a.idx, x)
+        return mv_b
 
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P("nodes"), P("nodes"), P("nodes")),
-        out_specs=P("nodes"), check_rep=False)
+        out_specs=P("nodes"), check_vma=False)
     def mv(data, idx, x_local):
         xg = jax.lax.all_gather(x_local, "nodes", tiled=True)
         return spmv_seq_ref(data, idx, xg)
 
-    return lambda x: mv(a.data, a.idx, x)
+    return mv
 
 
 def _dot_lane(m: int, n_nodes: int, lane: int = 8) -> int:
@@ -146,7 +154,7 @@ def sharded_dot(mesh: Mesh, m: int, lane: int = 8, batch: int = 0):
     if batch:
         @functools.partial(shard_map, mesh=mesh,
                            in_specs=(P(None, "nodes"), P(None, "nodes")),
-                           out_specs=P(), check_rep=False)
+                           out_specs=P(), check_vma=False)
         def dot_b(u, v):
             part = jnp.stack([_slab_dot(u[i], v[i], lane)
                               for i in range(batch)])
@@ -156,7 +164,7 @@ def sharded_dot(mesh: Mesh, m: int, lane: int = 8, batch: int = 0):
 
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(P("nodes"), P("nodes")), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
     def dot(u, v):
         return jax.lax.psum(_slab_dot(u, v, lane), "nodes")
 
@@ -244,7 +252,7 @@ def _sharded_sweep_precond(problem: Problem, mesh: Mesh):
                                   pc.mid_blocks)))
 
         @partial(shard_map, mesh=mesh, in_specs=(P("nodes"),) * 9,
-                 out_specs=P("nodes"), check_rep=False)
+                 out_specs=P("nodes"), check_vma=False)
         def apply_local(lo_idx, lo_n, lo_data, up_idx, up_n, up_data, dinv,
                         mid, r):
             base = jax.lax.axis_index("nodes") * per     # global -> slab ids
@@ -259,7 +267,7 @@ def _sharded_sweep_precond(problem: Problem, mesh: Mesh):
                                   pc.dinv_b)))
 
         @partial(shard_map, mesh=mesh, in_specs=(P("nodes"),) * 9,
-                 out_specs=P("nodes"), check_rep=False)
+                 out_specs=P("nodes"), check_vma=False)
         def apply_local(lo_idx, lo_n, lo_data, up_idx, up_n, up_data,
                         dinv_f, dinv_b, r):
             base = jax.lax.axis_index("nodes") * per
@@ -312,6 +320,68 @@ def _ops_from_parts(backend, mv, precond, dot, variant, constrain):
     return SolverOps(backend, mv, matvec_dot, precond, update, variant, dot)
 
 
+@dataclasses.dataclass(frozen=True)
+class _MeshOps:
+    """``bind`` of the sharded (``mesh`` set) and mesh-mirror (``mesh``
+    None) bundles over the operands (data, idx[, pinv]): the Block-ELL
+    matrix, and the block-Jacobi inverse blocks unless ``precond`` is
+    another preconditioner's apply."""
+    mesh: Mesh | None
+    n_nodes: int
+    m: int
+    lane: int
+    batch: int = 0
+    precond: Callable | None = None
+    variant: str = ""
+
+    def __call__(self, operands):
+        from repro.core.ops import jacobi_apply
+        from repro.kernels.spmv.ref import spmv_seq_ref
+
+        data, idx, *pinv = operands
+        batch = self.batch
+        if self.precond is not None:
+            precond = self.precond
+        elif batch:
+            precond = lambda r: jnp.stack([jacobi_apply(pinv[0], r[i])
+                                           for i in range(batch)])
+        else:
+            precond = lambda r: jacobi_apply(pinv[0], r)
+        if self.mesh is not None:
+            spmv = _sharded_spmv(self.mesh, batch)
+            vec = NamedSharding(self.mesh,
+                                P(None, "nodes") if batch else P("nodes"))
+            return _ops_from_parts(
+                "sharded", lambda x: spmv(data, idx, x), precond,
+                sharded_dot(self.mesh, self.m, self.lane, batch=batch),
+                self.variant,
+                lambda v: jax.lax.with_sharding_constraint(v, vec))
+        mv1 = lambda x: spmv_seq_ref(data, idx, x)
+        dot1 = mesh_dot(self.n_nodes, self.m, self.lane)
+        if batch:
+            return _ops_from_parts(
+                "mesh-mirror",
+                lambda x: jnp.stack([mv1(x[i]) for i in range(batch)]),
+                precond,
+                lambda u, v: jnp.stack([dot1(u[i], v[i])
+                                        for i in range(batch)]),
+                "mesh-mirror", lambda v: v)
+        return _ops_from_parts("mesh-mirror", mv1, precond, dot1,
+                               f"mesh-mirror {self.variant}".strip(),
+                               lambda v: v)
+
+
+def _mesh_ops(problem: Problem, mesh, n_nodes: int, batch: int = 0,
+              precond=None, variant: str = ""):
+    from repro.core.ops import bound
+
+    a = problem.a
+    operands = ((a.data, a.idx) if precond is not None
+                else (a.data, a.idx, problem.pinv_blocks))
+    return bound(_MeshOps(mesh, n_nodes, problem.m, problem.part.bn, batch,
+                          precond, variant), operands)
+
+
 def sharded_solver_ops(problem: Problem, mesh: Mesh, batch: int = 0):
     """SolverOps bundle for the distributed runtime.
 
@@ -321,8 +391,8 @@ def sharded_solver_ops(problem: Problem, mesh: Mesh, batch: int = 0):
     (so XLA keeps the whole iteration SPMD-partitioned instead of
     replicating intermediates), and the pᵀq / rᵀz dots lower to per-node
     partials + the natural psum across the "nodes" axis. Cached per
-    (problem, mesh): the jitted chunk runners treat the bundle as a static
-    argument.
+    (problem, mesh); the placed matrix and block-Jacobi blocks are the
+    bundle's operands.
 
     Every registered preconditioner is accepted: block-Jacobi keeps the
     seed's einsum over re-placed blocks, SSOR/IC(0) run their node-local
@@ -348,33 +418,21 @@ def sharded_solver_ops(problem: Problem, mesh: Mesh, batch: int = 0):
         return cache[key]
     n = mesh.shape["nodes"]
     variant = ""
+    precond = None
     name = problem.precond_name
-    if batch:
-        if name != "jacobi":
-            raise NotImplementedError(
-                f"batched sharded runtime supports the block-Jacobi "
-                f"preconditioner only (got {name!r})")
-        vec = NamedSharding(mesh, P(None, "nodes"))
-        mv = sharded_matvec(problem.a, mesh, batch=batch)
-        precond = lambda r: jnp.stack([problem.apply_precond(r[i])
-                                       for i in range(batch)])
-        dot = sharded_dot(mesh, problem.m, problem.part.bn, batch=batch)
-    else:
-        vec = NamedSharding(mesh, P("nodes"))
-        mv = sharded_matvec(problem.a, mesh)
-        if name == "jacobi":
-            precond = problem.apply_precond
-        elif name == "chebyshev":
-            precond, variant = _sharded_chebyshev_precond(problem, mesh)
-        elif name in ("ssor", "ic0"):
-            precond, variant = _sharded_sweep_precond(problem, mesh)
-        else:
-            raise NotImplementedError(
-                f"sharded runtime has no distributed apply for "
-                f"preconditioner {name!r}")
-        dot = sharded_dot(mesh, problem.m, problem.part.bn)
-    constrain = lambda v: jax.lax.with_sharding_constraint(v, vec)
-    ops = _ops_from_parts("sharded", mv, precond, dot, variant, constrain)
+    if batch and name != "jacobi":
+        raise NotImplementedError(
+            f"batched sharded runtime supports the block-Jacobi "
+            f"preconditioner only (got {name!r})")
+    if name == "chebyshev":
+        precond, variant = _sharded_chebyshev_precond(problem, mesh)
+    elif name in ("ssor", "ic0"):
+        precond, variant = _sharded_sweep_precond(problem, mesh)
+    elif name != "jacobi":
+        raise NotImplementedError(
+            f"sharded runtime has no distributed apply for "
+            f"preconditioner {name!r}")
+    ops = _mesh_ops(problem, mesh, n, batch, precond, variant)
     # re-fetch: building the bundle may have *cleared* the cache attribute
     # (twin adoption drops every closure cache, this one included)
     cache = getattr(problem, "_sharded_ops_cache", None)
@@ -413,31 +471,18 @@ def mesh_mirror_ops(problem: Problem, n_nodes: int, batch: int = 0):
             raise ValueError(
                 f"mesh mirror needs one partition slab per simulated node: "
                 f"asked n={n_nodes}, partition has {problem.part.n_nodes}")
-        a = problem.a
-        matvec = lambda x: spmv_seq_ref(a.data, a.idx, x)
         variant = ""
+        precond = None
         name = problem.precond_name
-        if batch:
-            if name != "jacobi":
-                raise NotImplementedError(
-                    f"batched mesh mirror supports the block-Jacobi "
-                    f"preconditioner only (got {name!r})")
-            mv1, dot1 = matvec, mesh_dot(n_nodes, problem.m, problem.part.bn)
-            cache[key] = _ops_from_parts(
-                "mesh-mirror",
-                lambda x: jnp.stack([mv1(x[i]) for i in range(batch)]),
-                lambda r: jnp.stack([problem.apply_precond(r[i])
-                                     for i in range(batch)]),
-                lambda u, v: jnp.stack([dot1(u[i], v[i])
-                                        for i in range(batch)]),
-                "mesh-mirror", lambda v: v)
-            return cache[key]
-        if name == "jacobi":
-            precond = problem.apply_precond
-        elif name == "chebyshev":
+        if batch and name != "jacobi":
+            raise NotImplementedError(
+                f"batched mesh mirror supports the block-Jacobi "
+                f"preconditioner only (got {name!r})")
+        if name == "chebyshev":
             from repro.kernels.chebyshev.chebyshev import cheb_recurrence
 
-            pc = problem.precond
+            a, pc = problem.a, problem.precond
+            matvec = lambda x: spmv_seq_ref(a.data, a.idx, x)
             precond = lambda r: cheb_recurrence(matvec, r, lo=pc.lo,
                                                 hi=pc.hi, degree=pc.degree)
             variant = "spmv-distributed chebyshev"
@@ -448,12 +493,10 @@ def mesh_mirror_ops(problem: Problem, n_nodes: int, batch: int = 0):
                        else f"node-local {pc.name}")
             cache = {}
             problem._mesh_mirror_cache = cache    # adoption dropped the attr
-        else:
+        elif name != "jacobi":
             raise NotImplementedError(name)
-        cache[key] = _ops_from_parts(
-            "mesh-mirror", matvec, precond,
-            mesh_dot(n_nodes, problem.m, problem.part.bn),
-            f"mesh-mirror {variant}".strip(), lambda v: v)
+        cache[key] = _mesh_ops(problem, None, n_nodes, batch, precond,
+                               variant)
     return cache[key]
 
 
@@ -513,7 +556,7 @@ def ring_halo_matvec(a: BlockEll, part, mesh: Mesh, halo_tiles: int):
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P("nodes"), P("nodes"), P("nodes")),
-        out_specs=P("nodes"), check_rep=False)
+        out_specs=P("nodes"), check_vma=False)
     def mv(data, idx_l, x):
         # x: local slab (rows_per_node,) -> tiles (cpt, bn)
         xt = x.reshape(cpt, bn)
@@ -588,7 +631,7 @@ def aspmv_push(plan, part, mesh: Mesh):
         perm = perms[k]
 
         @partial(shard_map, mesh=mesh, in_specs=(P("nodes"), P("nodes")),
-                 out_specs=(P("nodes"), P("nodes")), check_rep=False)
+                 out_specs=(P("nodes"), P("nodes")), check_vma=False)
         def push(x_local, idx_local):
             xt = x_local.reshape(cpt, bn)
             me = jax.lax.axis_index("nodes")
@@ -691,7 +734,7 @@ def redundancy_queue(plan, part, mesh: Mesh, batch: int = 0):
 
         @partial(shard_map, mesh=mesh,
                  in_specs=(P(None, "nodes"),) + (P("nodes"),) * len(statics),
-                 out_specs=P(None, "nodes"), check_rep=False)
+                 out_specs=P(None, "nodes"), check_vma=False)
         def push_b(x_local, *stat):
             send = stat[:phi]
             rslot = stat[phi:2 * phi]
@@ -722,7 +765,7 @@ def redundancy_queue(plan, part, mesh: Mesh, batch: int = 0):
 
     @partial(shard_map, mesh=mesh,
              in_specs=(P("nodes"),) * (1 + len(statics)),
-             out_specs=P("nodes"), check_rep=False)
+             out_specs=P("nodes"), check_vma=False)
     def push(x_local, *stat):
         send = stat[:phi]
         rslot = stat[phi:2 * phi]
@@ -761,7 +804,7 @@ def _node_axis_zeroer(mesh: Mesh, axis: int):
     spec = P(*([None] * axis + ["nodes"]))
 
     @functools.partial(shard_map, mesh=mesh, in_specs=(spec, P()),
-                       out_specs=spec, check_rep=False)
+                       out_specs=spec, check_vma=False)
     def zero(v, dead):
         me = jax.lax.axis_index("nodes")
         return jnp.where(dead[me], jnp.zeros_like(v), v)

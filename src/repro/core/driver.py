@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from typing import Callable, Optional
 
@@ -189,9 +190,22 @@ def _find_convergence(norms: np.ndarray, thresh: float) -> int:
 # the same rr_every residual-replacement gate as the chunk runner, or a
 # replacement landing on the reconstruction point would be silently skipped
 # and the post-recovery trajectory would fork off the failure-free one.
-_resume_step = jax.jit(esrp.numeric_step, static_argnums=(1, 3, 4))
+_resume_step = jax.jit(esrp.numeric_step, static_argnums=(3, 4))
 
 
+def _full_precision_matmuls(fn):
+    """Trace ``fn``'s programs with f32 matmuls at full precision. On a TPU
+    an f32 dot defaults to one bf16 pass, which would leave every inner
+    product, the standalone preconditioner and Alg. 2's inner solves at
+    about three digits; elsewhere the setting changes nothing."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
+@_full_precision_matmuls
 def solve_resilient(
     problem: Problem,
     strategy: str = "esrp",            # "esrp" | "imcr" | "none"
@@ -324,9 +338,9 @@ def solve_resilient(
     if ops is None:
         if matvec is not None:
             # cache the closure bundle on the problem so repeated solves with
-            # the same matvec reuse the jitted chunk runners (the bundle is
-            # their static argument), without pinning the problem in a
-            # module-global cache
+            # the same matvec reuse the jitted chunk runners (a closure
+            # bundle keys their compile cache by identity), without pinning
+            # the problem in a module-global cache
             cache = getattr(problem, "_closure_ops_cache", None)
             if cache is None:
                 cache = {}

@@ -28,7 +28,7 @@ storage".
 The failed set may span several (non-contiguous) nodes — one simultaneous
 multi-node event of the scenario engine resolves to ONE reconstruction over
 the union I_f of all its failed rows (arXiv:1907.13077's simultaneous case);
-the inner solves are zero-RHS-safe (``run_pcg`` returns x = 0, rel = 0.0
+the inner solves are zero-RHS-safe (``solve_pcg`` returns x = 0, rel = 0.0
 instead of NaN when a strip of v or w is exactly zero).
 
 Where p^(j-1), p^(j) come from is the caller's business: the single-device
@@ -49,8 +49,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import failures
-from repro.core.pcg import _expand, run_pcg, run_pcg_batched
-from repro.sparse.blockell import BlockEll
+from repro.core.ops import SolverOps, bound, jacobi_apply, make_closure_ops
+from repro.core.pcg import _expand, solve_pcg, solve_pcg_batched
+from repro.sparse.blockell import BlockEll, ell_matvec
 from repro.sparse.matrices import Problem
 
 
@@ -71,8 +72,7 @@ class ReconstructionOps:
     diag_f: jax.Array                # (|I_f|/b, b, b) raw diag blocks (= P_ff^{-1})
     pinv_f: jax.Array                # (|I_f|/b, b, b) inverse blocks (A_ff precond)
     b_f: jax.Array
-    precond_f: object = None         # stable closure: jitted inner solves
-    #                                  must see the same callable each call
+    inner: SolverOps = None          # line 8: A_ff with block-Jacobi pinv_f
     p_offdiag: object = None         # line 5: r_surv -> P_{f,I\f} r_{I\f}
     #                                  (None = exactly zero, block-Jacobi)
     p_solve: object = None           # line 6: v -> r_f solving P_ff r_f = v
@@ -117,10 +117,6 @@ class ReconstructionOps:
         blk_ids = np.unique(f_rows // b_blk)
         pinv_f = problem.pinv_blocks[blk_ids]
 
-        def precond_f(r, _pinv=pinv_f, _b=b_blk):
-            return jnp.einsum("nij,nj->ni", _pinv,
-                              r.reshape(-1, _b)).reshape(-1)
-
         # recovery-aware lines 5-6: preconditioners with off-diagonal
         # coupling supply their own local operators; block-Jacobi (or a
         # legacy Problem without a precond object) keeps the seed shortcut
@@ -139,8 +135,18 @@ class ReconstructionOps:
             a_rows_f=a_rows_f, a_ff=a_ff,
             diag_f=problem.diag_blocks[blk_ids],
             pinv_f=pinv_f,
-            b_f=problem.b[f_rows], precond_f=precond_f,
+            b_f=problem.b[f_rows],
+            inner=bound(_inner_ops, (a_ff.data, a_ff.idx, pinv_f)),
             p_offdiag=p_offdiag, p_solve=p_solve)
+
+
+def _inner_ops(operands) -> SolverOps:
+    """``bind`` of the line-8 inner solve: A_ff's Block-ELL (data, idx)
+    with the block-Jacobi inverse blocks of its rows. Module-level, so
+    reconstructions of one failed-set size share the compiled solve."""
+    data, idx, pinv = operands
+    return make_closure_ops(lambda x: ell_matvec(data, idx, x),
+                            lambda r: jacobi_apply(pinv, r))
 
 
 def _span(tracer, name: str, **args):
@@ -152,7 +158,8 @@ def _span(tracer, name: str, **args):
 
 def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
                 beta_prev: jax.Array, r_surv: jax.Array, x_surv: jax.Array,
-                inner_rtol: float = 1e-14, inner_max_iters: int = 20_000,
+                inner_rtol: float | None = None,
+                inner_max_iters: int = 20_000,
                 b_f: jax.Array | None = None, tracer=None):
     """Run Alg. 2. Inputs are full-length vectors; only surviving (resp.
     redundant-copy) entries are read, enforced by masking. Returns the failed
@@ -176,7 +183,14 @@ def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
     ``b_f`` overrides the RHS entries baked into ``ops`` (line 7) — the
     batched driver solves B different right-hand sides against the one
     static strip set, so it passes ``rhs[:, f_rows]`` here.
+
+    ``inner_rtol`` defaults to the paper's 1e-14 where the dtype can reach
+    it and to ten machine epsilons where it cannot (1.2e-6 in float32), so
+    an f32 inner solve stops at its floor instead of running out its
+    iteration budget.
     """
+    if inner_rtol is None:
+        inner_rtol = max(1e-14, 10 * float(jnp.finfo(r_surv.dtype).eps))
     sync = jax.block_until_ready if tracer is not None else (lambda x: x)
     mask = jnp.asarray(ops.mask)
     f_rows = jnp.asarray(ops.f_rows)
@@ -232,9 +246,9 @@ def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
 
     with _span(tracer, "alg2_line8_aff_solve",
                inner_rtol=inner_rtol) as sp8:
-        solve = run_pcg if not batched else run_pcg_batched
-        state, rel = solve(ops.a_ff.matvec, ops.precond_f, w,
-                           inner_rtol, inner_max_iters)            # line 8
+        solve = solve_pcg if not batched else solve_pcg_batched
+        state, rel = solve(ops.inner, w, inner_rtol,
+                           inner_max_iters)                        # line 8
         x_f = sync(state.x)
         if sp8 is not None:
             sp8.args["inner_rel"] = (float(rel) if not batched

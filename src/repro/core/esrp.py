@@ -253,7 +253,7 @@ def esrp_step(st: ESRPState, ops: SolverOps, T: int,
     return st._replace(pcg=numeric_step(st.pcg, ops, b, rr_every, gated))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 5, 6, 8, 9, 10))
+@functools.partial(jax.jit, static_argnums=(2, 3, 5, 6, 8, 9, 10))
 @sync_free
 def run_chunk(st: ESRPState, ops: SolverOps, T: int, n_iters: int,
               thresh: jax.Array | None = None,

@@ -100,7 +100,7 @@ def imcr_step(st: IMCRState, ops: SolverOps, T: int, phi: int,
     return st._replace(pcg=pcg_iterate_ops(st.pcg, ops))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 7, 8))
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 7, 8))
 @sync_free
 def run_chunk(st: IMCRState, ops: SolverOps, T: int, phi: int,
               rows_per_node: int, n_iters: int,

@@ -324,8 +324,20 @@ def run_pcg(matvec: Callable, precond: Callable, b: jax.Array,
     into a reconstructed state (a zero RHS there is a legal input: e.g. a
     failed block whose residual strip is exactly zero).
     """
-    ops = make_closure_ops(matvec, precond)
-    state = pcg_init(matvec, precond, b, x0)
+    return _solve(make_closure_ops(matvec, precond), b, rtol, max_iters, x0)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def solve_pcg(ops: SolverOps, b: jax.Array, rtol: float = 1e-8,
+              max_iters: int = 100_000, x0: jax.Array | None = None
+              ) -> tuple[PCGState, jax.Array]:
+    """``run_pcg`` through a bundle (its ``dot`` is not used): the
+    program takes the bundle's operands as arguments."""
+    return _solve(ops, b, rtol, max_iters, x0)
+
+
+def _solve(ops, b, rtol, max_iters, x0):
+    state = pcg_init(ops.matvec, ops.precond, b, x0)
     bnorm = jnp.linalg.norm(b)
     thresh = rtol * bnorm
     nonzero = bnorm > 0
@@ -366,8 +378,23 @@ def run_pcg_batched(matvec: Callable, precond: Callable, b: jax.Array,
     in f64 to ``run_pcg(matvec, precond, b[i], ...)``. Zero-RHS members
     (the micro-batcher's padding) resolve to x = 0 / rel = 0 at iteration 0,
     exactly like the unbatched guard."""
+    return _solve_batched(make_closure_ops(matvec, precond), b, rtol,
+                          max_iters, x0)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def solve_pcg_batched(ops: SolverOps, b: jax.Array, rtol: float = 1e-8,
+                      max_iters: int = 100_000,
+                      x0: jax.Array | None = None
+                      ) -> tuple[PCGState, jax.Array]:
+    """``run_pcg_batched`` through an unbatched bundle, applied per
+    member: the program takes the bundle's operands as arguments."""
+    return _solve_batched(ops, b, rtol, max_iters, x0)
+
+
+def _solve_batched(ops, b, rtol, max_iters, x0):
     nb = b.shape[0]
-    ops = batch_ops(make_closure_ops(matvec, precond), nb)
+    ops = batch_ops(ops, nb)
     state = pcg_init(ops.matvec, ops.precond, b, x0, dot=ops.dot)
     bnorm = jnp.linalg.norm(b, axis=-1)
     thresh = rtol * bnorm

@@ -203,7 +203,7 @@ def corrupt(st, ev: SDCEvent, part: Partition):
 # --------------------------------------------------------------------------- #
 # invariant evaluation
 # --------------------------------------------------------------------------- #
-@functools.partial(jax.jit, static_argnums=(0, 3))
+@functools.partial(jax.jit, static_argnums=(3,))
 def _invariant_values(ops, pcg, b, n_slabs):
     """Device computation for one check: the residual-deviation slab norms,
     the orthogonality violation and its slab partials, the z-invariant slab

@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import not_on_chip
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                   *, sm_scale, causal, window, bq, bk, kv_len):
@@ -82,6 +84,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     bq: int = 128, bk: int = 128,
                     interpret: bool = False) -> jax.Array:
     """q: (BH, Sq, dh); k, v: (BH, Skv, dh). Returns (BH, Sq, dh)."""
+    if not interpret:
+        not_on_chip("flash attention", "its index maps and rank-1 row "
+                    "statistics were never made Mosaic-legal")
     bh, sq, dh = q.shape
     skv = k.shape[1]
     bq = min(bq, sq)

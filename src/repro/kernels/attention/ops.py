@@ -7,9 +7,9 @@ Self-attention only (sq == skv) for the causal path.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_backend
 from repro.kernels.attention.flash import flash_attention
 from repro.kernels.attention.ref import attention_ref
 
@@ -20,8 +20,7 @@ def gqa_flash(q, k, v, *, causal=True, window=0, backend="auto",
     b, s, h, dh = q.shape
     kh = k.shape[2]
     g = h // kh
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = resolve_backend(backend, q.dtype)
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, s, dh)
     kf = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1).reshape(b * h, s, dh)
     vf = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1).reshape(b * h, s, dh)

@@ -1,15 +1,15 @@
 """Dispatch wrapper for block-Jacobi apply."""
 from __future__ import annotations
 
-import jax
 
+from repro.kernels import resolve_backend
 from repro.kernels.block_jacobi.block_jacobi import block_jacobi_apply
 from repro.kernels.block_jacobi.ref import block_jacobi_apply_ref
 
 
-def precond_apply(pinv_blocks, r, *, backend: str = "auto", rows: int = 256):
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+def precond_apply(pinv_blocks, r, *, backend: str = "auto",
+                  rows: int | None = None):
+    backend = resolve_backend(backend, r.dtype)
     if backend == "jnp":
         return block_jacobi_apply_ref(pinv_blocks, r)
     return block_jacobi_apply(pinv_blocks, r, rows=rows,

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels import resolve_backend
 from repro.kernels.spmv.ref import spmv_ref
 from repro.kernels.spmv.spmv import spmv as spmv_pallas
 from repro.sparse.blockell import BlockEll
@@ -13,8 +14,7 @@ def blockell_matvec(a: BlockEll, x: jax.Array, *, backend: str = "auto"):
     """backend: "auto" (pallas on TPU else jnp), "pallas", "interpret",
     "jnp". (The fused SpMV+dot variant is routed by repro.core.ops, which
     owns the solver-side backend dispatch.)"""
-    if backend == "auto":
-        backend = ("pallas" if jax.default_backend() == "tpu" else "jnp")
+    backend = resolve_backend(backend, x.dtype)
     if backend == "jnp":
         return spmv_ref(a.data, a.idx, x)
     return spmv_pallas(a.data, a.idx, x,

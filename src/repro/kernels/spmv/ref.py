@@ -22,18 +22,32 @@ def spmv_ref(data: jax.Array, idx: jax.Array, x: jax.Array) -> jax.Array:
     return out.reshape(rt * bm)
 
 
+_NT_BATCHED = (((1,), (2,)), ((0,), (0,)))   # per row tile r: x_r · tile_rᵀ
+
+
+def tile_products(tiles: jax.Array, xg: jax.Array) -> jax.Array:
+    """(rt, bm, bn) tiles, (rt, bn) gathered x tiles -> (rt, bm): every row
+    tile's tile · x as the kernel forms it, the NT matmul x · tileᵀ."""
+    return jax.lax.dot_general(xg, tiles, _NT_BATCHED,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=tiles.dtype)
+
+
+@jax.jit
 def spmv_seq_ref(data: jax.Array, idx: jax.Array, x: jax.Array) -> jax.Array:
     """SpMV with the kernel's accumulation order: acc += data[:, k] @ x_k,
-    k ascending — one (bm, bn) @ (bn,) product per slot, summed sequentially
-    exactly like the Pallas grid's inner dimension."""
+    k ascending — one ``tile_products`` per slot, summed sequentially
+    exactly like the Pallas grid's inner dimension. Jitted, so that it is
+    one fused program whether it is called eagerly or from a solver's."""
     rt, kmax, bm, bn = data.shape
     xb = x.reshape(-1, bn)
     acc = jnp.zeros((rt, bm), data.dtype)
     for k in range(kmax):
-        acc = acc + jnp.einsum("rij,rj->ri", data[:, k], xb[idx[:, k]])
+        acc = acc + tile_products(data[:, k], xb[idx[:, k]])
     return acc.reshape(rt * bm)
 
 
+@jax.jit
 def spmv_dot_ref(data: jax.Array, idx: jax.Array,
                  x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Fused y = A @ x and xᵀy, mirroring ``spmv_dot``'s per-row-tile
@@ -42,7 +56,7 @@ def spmv_dot_ref(data: jax.Array, idx: jax.Array,
     xb = x.reshape(-1, bn)
     acc = jnp.zeros((rt, bm), data.dtype)
     for k in range(kmax):
-        acc = acc + jnp.einsum("rij,rj->ri", data[:, k], xb[idx[:, k]])
+        acc = acc + tile_products(data[:, k], xb[idx[:, k]])
     partial = jnp.sum(acc * x.reshape(rt, bm), axis=1)    # (rt,)
     # keep the (per-row-tile partials -> final sum) association: without the
     # barrier XLA collapses the two reduces into one flat sum, breaking the

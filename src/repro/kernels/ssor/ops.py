@@ -1,21 +1,20 @@
 """Dispatch wrapper for the SSOR apply."""
 from __future__ import annotations
 
-import jax
 
+from repro.kernels import resolve_backend
 from repro.kernels.ssor.ref import ssor_apply_ref
 from repro.kernels.ssor.ssor import ssor_apply
 
 
 def ssor_precond_apply(lo_idx, lo_n, lo_data, up_idx, up_n, up_data, dinv,
                        mid_blocks, r, *, backend: str = "auto",
-                       rows: int = 256, lo_wf=None, up_wf=None):
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+                       lo_wf=None, up_wf=None):
+    backend = resolve_backend(backend, r.dtype)
     if backend == "jnp":
         return ssor_apply_ref(lo_idx, lo_n, lo_data, up_idx, up_n, up_data,
                               dinv, mid_blocks, r, lo_wf=lo_wf, up_wf=up_wf)
     return ssor_apply(lo_idx, lo_n, lo_data, up_idx, up_n, up_data, dinv,
-                      mid_blocks, r, rows=rows,
+                      mid_blocks, r,
                       interpret=(backend == "interpret"),
                       lo_wf=lo_wf, up_wf=up_wf)

@@ -24,9 +24,9 @@ from repro.kernels.block_jacobi.block_jacobi import block_jacobi_apply
 from repro.kernels.trisweep.trisweep import block_sweep, wavefront_sweep
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def ssor_apply(lo_idx, lo_n, lo_data, up_idx, up_n, up_data, dinv,
-               mid_blocks, r, *, rows: int = 256, interpret: bool = False,
+               mid_blocks, r, *, interpret: bool = False,
                lo_wf=None, up_wf=None):
     """``lo_wf``/``up_wf``: optional level-major ``trisweep.ops.Wavefront``
     bundles — when present the substitutions run one grid step per
@@ -37,7 +37,7 @@ def ssor_apply(lo_idx, lo_n, lo_data, up_idx, up_n, up_data, dinv,
     else:
         y = block_sweep(lo_idx, lo_n, lo_data, dinv, r, reverse=False,
                         interpret=interpret)
-    w = block_jacobi_apply(mid_blocks, y, rows=rows, interpret=interpret)
+    w = block_jacobi_apply(mid_blocks, y, interpret=interpret)
     if up_wf is not None:
         return wavefront_sweep(up_wf.rows, up_wf.n, up_wf.idx, up_wf.data,
                                up_wf.dinv, w, interpret=interpret)

@@ -7,6 +7,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_backend
 from repro.kernels.trisweep.ref import block_sweep_ref, wavefront_sweep_ref
 from repro.kernels.trisweep.trisweep import block_sweep, wavefront_sweep
 
@@ -36,8 +37,7 @@ def sweep(idx, n, data, dinv, r, *, reverse: bool = False,
     wavefront kernels run one grid step per elimination-DAG level (all
     independent block rows of a level together) instead of one per row —
     bit-identical results either way (same per-row arithmetic)."""
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = resolve_backend(backend, r.dtype)
     if schedule is not None:
         if backend == "jnp":
             return wavefront_sweep_ref(schedule.rows, schedule.n,

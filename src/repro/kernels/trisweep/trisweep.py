@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import not_on_chip
+
 
 def _sweep_kernel(idx_ref, n_ref, data_ref, dinv_ref, r_ref, y_ref,
                   *, reverse: bool, nbr: int):
@@ -95,6 +97,9 @@ def wavefront_sweep(rows: jax.Array, n: jax.Array, idx: jax.Array,
     bit-identical to ``block_sweep`` in f64 (rows within a level are
     mutually independent by construction).
     """
+    if not interpret:
+        not_on_chip("wavefront triangular sweep",
+                    "rank-1 blocks that are not (8, 128) tiles")
     n_levels, width, kmax, b, _ = data.shape
     m = r.shape[0]
     mp = m + b
@@ -127,6 +132,9 @@ def block_sweep(idx: jax.Array, n: jax.Array, data: jax.Array,
     """idx: (nbr, kmax) int32 column-block ids (0-padded); n: (nbr,) int32
     valid slots; data: (nbr, kmax, b, b); dinv: (nbr, b, b); r: (m,).
     Returns y with (D̂ + T) y = r."""
+    if not interpret:
+        not_on_chip("triangular sweep",
+                    "rank-1 blocks that are not (8, 128) tiles")
     nbr, kmax, b, _ = data.shape
     m = r.shape[0]
     if m != nbr * b:

@@ -1,12 +1,14 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"      # host devices only: never a chip
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + (
+    " --xla_force_host_platform_device_count=512")).strip()
 
 DOC = """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 MUST be run as its own process (``python -m repro.launch.dryrun ...``): the
-first two lines force 512 host platform devices BEFORE any other import so
-``jax.make_mesh`` can build the production meshes; smoke tests and benchmarks
-must never import this module.
+first lines pin the CPU and force 512 host platform devices BEFORE any
+other import so ``jax.make_mesh`` can build the production meshes; smoke
+tests and benchmarks must never import this module.
 
 Per cell it lowers the right step function (train_step / prefill_step /
 decode_step) against ShapeDtypeStruct inputs (no allocation), compiles it,

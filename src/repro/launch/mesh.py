@@ -12,6 +12,7 @@ device state (smoke tests must keep seeing 1 CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.models import sharding
 
@@ -19,7 +20,8 @@ from repro.models import sharding
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def bindings(multi_pod: bool = False, profile: str = "2d") -> dict:
@@ -73,6 +75,7 @@ def activate(mesh, multi_pod: bool = False, profile: str = "2d"):
 
 def make_test_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for multi-device subprocess tests."""
-    mesh = jax.make_mesh((n_data, n_model), ("data", "model"))
+    mesh = jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     sharding.set_context(mesh, bindings(False))
     return mesh

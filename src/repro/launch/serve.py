@@ -166,6 +166,8 @@ def run_lm(args):
 
 
 def main():
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     # shared
     ap.add_argument("--batch", type=int, default=8,

@@ -93,9 +93,10 @@ class Preconditioner(abc.ABC):
         """Backend-specific closure r -> z = P r."""
 
     def make_apply(self, backend: str = "jnp") -> Callable:
-        """Cached per backend: the jitted chunk runners treat the SolverOps
-        bundle (which holds this closure) as a static argument, so the same
-        object must come back on every call. "auto" resolves here, before
+        """Cached per backend: the jitted chunk runners key their compiled
+        programs on the SolverOps bundle's ``bind``, which holds this
+        closure, so the same object must come back on every call. "auto"
+        resolves here, before
         the cache and the subclasses' routing decisions, so "auto" and its
         resolution share one cache entry and the per-backend gates (e.g.
         the wavefront-vs-sequential sweep routing) see a concrete name."""

@@ -69,7 +69,6 @@ class BlockJacobi(Preconditioner):
         return cls(diag_blocks, pinv_blocks, block, m, dtype)
 
     def _make_apply(self, backend: str):
-        from repro.core.ops import pick_rows
         from repro.kernels.block_jacobi.block_jacobi import block_jacobi_apply
         from repro.kernels.block_jacobi.ref import block_jacobi_apply_ref
 
@@ -77,9 +76,7 @@ class BlockJacobi(Preconditioner):
         if backend == "jnp":
             return lambda r: block_jacobi_apply_ref(pinv, r)
         interp = backend == "interpret"
-        rows = pick_rows(self.m, self.block)
-        return lambda r: block_jacobi_apply(pinv, r, rows=rows,
-                                            interpret=interp)
+        return lambda r: block_jacobi_apply(pinv, r, interpret=interp)
 
     def static_state(self) -> dict:
         return {"diag_blocks": np.asarray(self.diag_blocks),

@@ -65,10 +65,8 @@ class SSOR(Preconditioner):
                    block, m, dtype, omega, sweep_mode)
 
     def _make_apply(self, backend: str):
-        from repro.core.ops import pick_rows
         from repro.kernels.ssor.ops import ssor_precond_apply
 
-        rows = pick_rows(self.m, self.block)
         args = (self.lo_idx, self.lo_n, self.lo_data, self.up_idx, self.up_n,
                 self.up_data, self.dinv, self.mid_blocks)
         # the wavefront shortens the sequential *kernel grid* (one step per
@@ -80,8 +78,7 @@ class SSOR(Preconditioner):
         lo_wf = self.lo_wf if wf else None
         up_wf = self.up_wf if wf else None
         return lambda r: ssor_precond_apply(*args, r, backend=backend,
-                                            rows=rows, lo_wf=lo_wf,
-                                            up_wf=up_wf)
+                                            lo_wf=lo_wf, up_wf=up_wf)
 
     def _pff_inner_precond(self, mask, f_rows):
         """Failed-slab-truncated SSOR matrix: B = M_ff with

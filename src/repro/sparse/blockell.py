@@ -26,6 +26,13 @@ import numpy as np
 from repro.sparse.partition import Partition
 
 
+def ell_matvec(data: jax.Array, idx: jax.Array, x: jax.Array) -> jax.Array:
+    """y = A x for Block-ELL (data, idx): gather the x tiles, one einsum."""
+    xb = x.reshape(-1, data.shape[-1])                # (col_tiles, bn)
+    gathered = xb[idx]                                # (rt, kmax, bn)
+    return jnp.einsum("rkij,rkj->ri", data, gathered).reshape(-1)
+
+
 @dataclasses.dataclass
 class BlockEll:
     """Block-ELL matrix.
@@ -115,10 +122,7 @@ class BlockEll:
 
     def matvec(self, x: jax.Array) -> jax.Array:
         """Reference jnp SpMV (the oracle; kernels/spmv accelerates this)."""
-        xb = x.reshape(-1, self.bn)                       # (col_tiles, bn)
-        gathered = xb[self.idx]                           # (rt, kmax, bn)
-        out = jnp.einsum("rkij,rkj->ri", self.data, gathered)
-        return out.reshape(-1)
+        return ell_matvec(self.data, self.idx, x)
 
     # -- partition-aware views ---------------------------------------- #
     def node_slice(self, part: Partition, s: int) -> "BlockEll":

@@ -138,26 +138,26 @@ class Problem:
         arrays are re-placed by ``comm.shard.place_problem``); other
         preconditioners delegate to their registered apply."""
         if self.precond is None or self.precond.name == "jacobi":
-            rb = r.reshape(-1, self.precond_block)
-            return jnp.einsum("nij,nj->ni", self.pinv_blocks, rb).reshape(-1)
+            from repro.core.ops import jacobi_apply
+            return jacobi_apply(self.pinv_blocks, r)
         return self.precond.apply(r, backend="jnp")
 
     def solver_ops(self, backend: str = "auto", batch: int = 0,
                    fused: bool = False):
         """The SolverOps execution bundle for this problem (see
-        repro.core.ops). Cached per (backend, batch): the jitted chunk
-        runners treat the bundle as a static argument, so reusing the same
-        object across solves reuses their compiled code instead of
-        re-tracing.
+        repro.core.ops). Cached per (backend, batch, fused): the bundle
+        holds the block-Jacobi diagonals it derives from the problem.
 
         backend: "auto" (pallas on TPU, jnp elsewhere) | "jnp" | "pallas" |
-        "interpret". ``batch`` > 0 returns the batched bundle whose ops
-        carry a leading B axis (one dispatch advances B members);
+        "interpret". On a TPU, "pallas" and "auto" refuse float64 data
+        with a ValueError (see ``repro.kernels.resolve_backend``).
+        ``batch`` > 0 returns the batched bundle whose ops carry a leading
+        B axis (one dispatch advances B members);
         ``fused=True`` picks its throughput mode (fused-batched einsums,
         per-member ~ulp instead of bit-identical — see core.ops)."""
         from repro.core.ops import make_problem_ops
-        if backend == "auto":
-            backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+        from repro.kernels import resolve_backend
+        backend = resolve_backend(backend, self.b.dtype)
         cache = getattr(self, "_ops_cache", None)
         if cache is None:
             cache = {}

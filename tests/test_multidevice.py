@@ -24,7 +24,8 @@ from repro.train.optimizer import AdamWConfig, init_opt_state
 from repro.train.train_step import make_train_step
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 sharding.set_context(mesh, mesh_lib.bindings(False))
 
 cfg = smoke_config("internlm2_1_8b")

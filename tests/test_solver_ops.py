@@ -9,6 +9,7 @@ kernels can be swapped into the paper's experiments without perturbing the
 trajectory-identity argument.
 """
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,9 @@ from tests._hypo import given, settings, st
 from repro.analysis import walker
 from repro.core import esrp
 from repro.core.driver import solve_resilient
-from repro.core.ops import make_closure_ops, pick_rows
+from repro.core.ops import make_closure_ops
+from repro.kernels.fused_pcg.fused_pcg import (ROW_TARGET, lanes_for,
+                                               update_rows)
 from repro.sparse.matrices import build_problem
 
 
@@ -73,6 +76,25 @@ def test_failure_recovery_bit_identical_across_backends(problems):
     assert rj.rel_residual == rp.rel_residual
     assert rj.target_iter == rp.target_iter
     assert rj.rel_residual < 1e-9
+
+
+@pytest.mark.parametrize("batch", [0, 2])
+@pytest.mark.parametrize("backend", ["jnp", "interpret"])
+def test_chunk_runner_takes_operands_as_arguments(problems, backend, batch):
+    """The bundle's arrays reach the compiled chunk runner as arguments:
+    closed over, they would be embedded in the program as constants (5.4
+    GB of literal at 128³)."""
+    p = problems["poisson3d"]
+    ops = p.solver_ops(backend, batch=batch)
+    rhs = p.b if not batch else jnp.stack([p.b] * batch)
+    st = esrp.esrp_init(ops.matvec, ops.precond, rhs, dot=ops.dot)
+    lowered = esrp.run_chunk.lower(st, ops, 5, 4, None)
+    shapes = [a.shape for a in jax.tree.leaves(lowered.args_info)]
+    for arr in ops.operands:
+        assert arr.shape in shapes
+    # no array constant over 1 KiB (2048 hex digits) in the program
+    literals = re.findall(r'dense<"0x([0-9A-F]*)"', lowered.as_text())
+    assert max(map(len, literals), default=0) < 2048
 
 
 def test_closure_ops_match_seed_numerics(problems):
@@ -187,7 +209,10 @@ def test_driver_report_consistent_with_and_without_failure(problems):
     assert r.rel_residual < 1e-9
 
 
-def test_pick_rows_divides():
-    for m, b in ((320, 10), (1280, 10), (1024, 4), (512, 8)):
-        rows = pick_rows(m, b)
-        assert m % rows == 0 and rows % b == 0 and rows <= 512
+def test_update_rows_divides():
+    for m, b in ((320, 10), (1280, 10), (1024, 4), (512, 8), (128 ** 3, 8)):
+        rows = update_rows(m, b)
+        lanes = lanes_for(rows, b)
+        assert m % rows == 0 and rows % lanes == 0 and lanes % b == 0
+        assert (rows <= ROW_TARGET and (rows // lanes) % 8 == 0) \
+            or rows == m
