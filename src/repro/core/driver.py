@@ -26,10 +26,8 @@ instead of blocking between chunks.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
-import time
 from typing import Callable, Optional
 
 import jax
@@ -44,7 +42,7 @@ from repro.core.failures import (FailureEvent, SDCEvent, failed_row_mask,
 from repro.core.ops import SolverOps, batch_ops, make_closure_ops
 from repro.core.pcg import PCGState, _vec_norm, freeze_pcg, residual_drift
 from repro.core.tiers import resolve_tier
-from repro.obs.trace import Tracer, jsonable
+from repro.obs.trace import Tracer, jsonable, span
 from repro.sparse.matrices import Problem
 
 # version stamp of the report JSON layout (EventReport/SolveReport.to_json);
@@ -53,14 +51,9 @@ from repro.sparse.matrices import Problem
 # report per member).
 # v3: SolveReport gained deadline_missed/retries (annotated by the serving
 # front-end) and final_n_nodes became required for report validation.
-REPORT_SCHEMA_VERSION = 3
-
-
-def _tspan(tr: Optional[Tracer], name: str, cat: str = "solver", **args):
-    """Span on ``tr``, or a no-op context (yielding None) when obs is off."""
-    if tr is None:
-        return contextlib.nullcontext()
-    return tr.span(name, cat=cat, **args)
+# v4: SolveReport gained prologue_s and runtime_s runs until the answer is on
+# the device; EventReport gained event_s and inner_iters.
+REPORT_SCHEMA_VERSION = 4
 
 
 @dataclasses.dataclass
@@ -74,6 +67,13 @@ class EventReport:
     inner_rel: float             # Alg.2 line-8 inner solve (nan: imcr/none)
     pff_iters: int = -1          # Alg.2 line-6 inner-CG iterations (-1 when
     #                              the preconditioner has a closed form)
+    inner_iters: int = 0         # Alg.2 line-8 inner PCG iterations (0:
+    #                              imcr/none, or no reconstruction)
+    event_s: float = 0.0         # fail-stop/sdc-repair: from the event
+    #                              span's start to the end of the resume step
+    #                              after it (its ||r|| read-back waits on the
+    #                              device); the event span alone when no
+    #                              resume step follows
     precond_reload_bytes: int = 0   # static preconditioner state the
     #                              replacement reloads from safe storage
     #                              (sharded runtime; see
@@ -113,7 +113,8 @@ class SolveReport:
     phi: int
     converged_iter: int
     rel_residual: float
-    runtime_s: float
+    runtime_s: float             # the solve span: entry until the answer is
+    #                              on the device and the reports are built
     recovery_s: float            # reconstruction ops only, summed over events
     wasted_iters: int            # rollback distance, summed over events
     target_iter: int             # last event's reconstruction point; -1 when
@@ -124,6 +125,8 @@ class SolveReport:
     aspmv_natural_bytes: int = 0
     aspmv_total_bytes: int = 0
     run_calls: int = 0           # chunk dispatches (no final-chunk re-run)
+    prologue_s: float = 0.0      # the prologue span: entry to the first
+    #                              chunk dispatch (plan, norms, state init)
     events: list[EventReport] = dataclasses.field(default_factory=list)
     precond_variant: str = ""    # e.g. "node-local ssor" on the sharded
     #                              runtime (SolverOps.variant)
@@ -184,6 +187,26 @@ def _find_convergence(norms: np.ndarray, thresh: float) -> int:
     return int(below[0]) if below.size else -1
 
 
+def _in_solve_span(fn):
+    """Run the solve inside its ``solve`` span. ``fn`` receives ``obs``
+    resolved: the Tracer to record into (``True`` makes a fresh one), or
+    None. Each report's ``runtime_s`` is the span's duration, so it ends
+    after the answer is on the device and the reports are built."""
+    @functools.wraps(fn)
+    def wrapped(*args, obs=None, **kwargs):
+        tr = obs if isinstance(obs, Tracer) else (
+            Tracer("solve_resilient") if obs else None)
+        with span("solve", "solver", tr) as sp:
+            out = fn(*args, obs=tr, **kwargs)
+        reports = out if isinstance(out, list) else [out]
+        for rep in reports:
+            rep.runtime_s = sp.dur_s
+            if tr is not None:
+                tr.record("solve_report", rep.to_json())
+        return out
+    return wrapped
+
+
 # module-level so the trace cache survives across solves (a fresh jit wrapper
 # per resume would recompile the same iteration every failure run).
 # esrp.numeric_step (not bare pcg_iterate_ops): the resume iteration must run
@@ -206,6 +229,7 @@ def _full_precision_matmuls(fn):
 
 
 @_full_precision_matmuls
+@_in_solve_span
 def solve_resilient(
     problem: Problem,
     strategy: str = "esrp",            # "esrp" | "imcr" | "none"
@@ -271,200 +295,197 @@ def solve_resilient(
     #                                    is bit-identical and compiles to
     #                                    the identical jaxpr (tested)
 ) -> "SolveReport | list[SolveReport]":
-    part = problem.part
-    pending = normalize_scenario(scenario, fail_at, failed_nodes,
-                                 part.n_nodes)
-    rhs_arr = None if rhs is None else jnp.asarray(rhs, problem.b.dtype)
-    batched = rhs_arr is not None and rhs_arr.ndim == 2
-    nbatch = int(rhs_arr.shape[0]) if batched else 0
-    if rhs_arr is not None and rhs_arr.shape[-1] != part.m:
-        raise ValueError(
-            f"rhs row length {rhs_arr.shape[-1]} != problem size {part.m}")
-    if failure_runtime is not None \
-            and getattr(failure_runtime, "batch", 0) != nbatch:
-        rt_batch = getattr(failure_runtime, "batch", 0)
-        if nbatch:
-            hint = (f"construct ShardedFailureRuntime(problem, mesh, "
-                    f"batch={nbatch}) to match the (B, M) rhs")
+    tr: Optional[Tracer] = obs        # resolved by _in_solve_span, which
+    solve_sp = tr.current if tr is not None else None    # opened this span
+    with span("prologue", "solver", tr) as pro_sp:
+        part = problem.part
+        pending = normalize_scenario(scenario, fail_at, failed_nodes,
+                                     part.n_nodes)
+        rhs_arr = None if rhs is None else jnp.asarray(rhs, problem.b.dtype)
+        batched = rhs_arr is not None and rhs_arr.ndim == 2
+        nbatch = int(rhs_arr.shape[0]) if batched else 0
+        if rhs_arr is not None and rhs_arr.shape[-1] != part.m:
+            raise ValueError(
+                f"rhs row length {rhs_arr.shape[-1]} != problem size {part.m}")
+        if failure_runtime is not None \
+                and getattr(failure_runtime, "batch", 0) != nbatch:
+            rt_batch = getattr(failure_runtime, "batch", 0)
+            if nbatch:
+                hint = (f"construct ShardedFailureRuntime(problem, mesh, "
+                        f"batch={nbatch}) to match the (B, M) rhs")
+            else:
+                hint = ("this solve is unbatched — construct "
+                        "ShardedFailureRuntime(problem, mesh) and leave the "
+                        "batch parameter at its default 0")
+            raise ValueError(
+                f"failure_runtime was built for batch={rt_batch} but this "
+                f"solve has batch={nbatch} — {hint}")
+        sdc_events = [e for e in pending if isinstance(e, SDCEvent)]
+        if sdc_events or sdc_policy is not None:
+            if strategy not in ("esrp", "none"):
+                raise ValueError(
+                    f"SDC detection/repair supports the esrp and none strategies "
+                    f"(got {strategy!r}): imcr's checkpoint protocol has no "
+                    f"per-iteration invariants to verify against")
+            if strategy == "esrp" and T == 1:
+                raise ValueError(
+                    "SDC with T=1 (ESR) is unsupported: ESR stores every "
+                    "iteration, so corrupted state would be committed to the "
+                    "redundancy queue before any check cadence could catch it — "
+                    "use T >= 2")
+            if strategy == "none" and any(e.target == "queue"
+                                          for e in sdc_events):
+                raise ValueError(
+                    'strategy="none" keeps no redundancy queue — there is no '
+                    '"queue" shard to corrupt')
+            if sdc_policy is None:
+                sdc_policy = sdc.SDCPolicy()
+        sdc_on = sdc_policy is not None
+        # on-device guard mode: the chunk runner verifies the invariants at
+        # every check boundary inside the scan and halts on a violation; the
+        # host only confirms + localizes at the halted state (sdc.run_checks)
+        sdc_guard = sdc_on and sdc_on_device
+        # per-push queue checksums: written at push time, compared at check and
+        # read time (only meaningful when something both stores and checks)
+        qsum_slabs = part.n_nodes if (sdc_on and strategy == "esrp") else 0
+        if elastic:
+            if strategy != "esrp":
+                raise ValueError(
+                    f"elastic shrunk-mesh recovery needs the esrp strategy (got "
+                    f"{strategy!r}): Alg. 2 reconstruction provides the complete "
+                    f"state the shrunk partition continues from")
+            if failure_runtime is not None or ops is not None \
+                    or matvec is not None:
+                raise ValueError(
+                    "elastic recovery re-partitions the problem and rebuilds its "
+                    "solver ops — it requires the default problem-built ops (no "
+                    "custom ops/matvec) and no sharded failure runtime")
+        tier = resolve_tier(storage_tier)
+        itemsize = np.dtype(problem.b.dtype).itemsize
+        if ops is None:
+            if matvec is not None:
+                # cache the closure bundle on the problem so repeated solves with
+                # the same matvec reuse the jitted chunk runners (a closure
+                # bundle keys their compile cache by identity), without pinning
+                # the problem in a module-global cache
+                cache = getattr(problem, "_closure_ops_cache", None)
+                if cache is None:
+                    cache = {}
+                    problem._closure_ops_cache = cache
+                key = (matvec, problem.apply_precond, nbatch)
+                if key not in cache:
+                    base = make_closure_ops(matvec, problem.apply_precond)
+                    cache[key] = batch_ops(base, nbatch) if batched else base
+                ops = cache[key]
+            else:
+                ops = problem.solver_ops(backend, batch=nbatch,
+                                         fused=batched and batch_fused)
+        matvec = ops.matvec
+        precond = ops.precond
+        b = rhs_arr if rhs_arr is not None else problem.b
+        bnorm = float(jnp.linalg.norm(b))
+        if batched:
+            # per-member threshold. Zero-RHS members (micro-batch padding) get
+            # +inf: their rnorm == 0 row freezes at iteration 0 instead of
+            # dividing by zero, and their report carries rel = 0 / converged
+            bnorm_v = _vec_norm(b)
+            thresh_dev = jnp.where(bnorm_v > 0, rtol * bnorm_v,
+                                   jnp.inf).astype(b.dtype)
+            thresh = np.asarray(thresh_dev)        # (B,) host copy, same values
+            conv_iter = np.full(nbatch, -1, np.int64)   # per-member first
+            #                                  crossing (set-once, absolute count)
         else:
-            hint = ("this solve is unbatched — construct "
-                    "ShardedFailureRuntime(problem, mesh) and leave the "
-                    "batch parameter at its default 0")
-        raise ValueError(
-            f"failure_runtime was built for batch={rt_batch} but this "
-            f"solve has batch={nbatch} — {hint}")
-    sdc_events = [e for e in pending if isinstance(e, SDCEvent)]
-    if sdc_events or sdc_policy is not None:
-        if strategy not in ("esrp", "none"):
-            raise ValueError(
-                f"SDC detection/repair supports the esrp and none strategies "
-                f"(got {strategy!r}): imcr's checkpoint protocol has no "
-                f"per-iteration invariants to verify against")
-        if strategy == "esrp" and T == 1:
-            raise ValueError(
-                "SDC with T=1 (ESR) is unsupported: ESR stores every "
-                "iteration, so corrupted state would be committed to the "
-                "redundancy queue before any check cadence could catch it — "
-                "use T >= 2")
-        if strategy == "none" and any(e.target == "queue"
-                                      for e in sdc_events):
-            raise ValueError(
-                'strategy="none" keeps no redundancy queue — there is no '
-                '"queue" shard to corrupt')
-        if sdc_policy is None:
-            sdc_policy = sdc.SDCPolicy()
-    sdc_on = sdc_policy is not None
-    # on-device guard mode: the chunk runner verifies the invariants at
-    # every check boundary inside the scan and halts on a violation; the
-    # host only confirms + localizes at the halted state (sdc.run_checks)
-    sdc_guard = sdc_on and sdc_on_device
-    # per-push queue checksums: written at push time, compared at check and
-    # read time (only meaningful when something both stores and checks)
-    qsum_slabs = part.n_nodes if (sdc_on and strategy == "esrp") else 0
-    if elastic:
-        if strategy != "esrp":
-            raise ValueError(
-                f"elastic shrunk-mesh recovery needs the esrp strategy (got "
-                f"{strategy!r}): Alg. 2 reconstruction provides the complete "
-                f"state the shrunk partition continues from")
-        if failure_runtime is not None or ops is not None \
-                or matvec is not None:
-            raise ValueError(
-                "elastic recovery re-partitions the problem and rebuilds its "
-                "solver ops — it requires the default problem-built ops (no "
-                "custom ops/matvec) and no sharded failure runtime")
-    tier = resolve_tier(storage_tier)
-    itemsize = np.dtype(problem.b.dtype).itemsize
-    if ops is None:
-        if matvec is not None:
-            # cache the closure bundle on the problem so repeated solves with
-            # the same matvec reuse the jitted chunk runners (a closure
-            # bundle keys their compile cache by identity), without pinning
-            # the problem in a module-global cache
-            cache = getattr(problem, "_closure_ops_cache", None)
-            if cache is None:
-                cache = {}
-                problem._closure_ops_cache = cache
-            key = (matvec, problem.apply_precond, nbatch)
-            if key not in cache:
-                base = make_closure_ops(matvec, problem.apply_precond)
-                cache[key] = batch_ops(base, nbatch) if batched else base
-            ops = cache[key]
+            thresh_dev = jnp.asarray(rtol * bnorm, b.dtype)
+            # host-side scans must compare against the *same* value the chunk
+            # runner's freeze uses, or (in f32) a norm between the two would
+            # freeze the device state without the host ever declaring convergence
+            thresh = float(thresh_dev)
+
+        mtr = tr is not None              # static: arms the chunk metrics ring
+
+        plan: Optional[RedundancyPlan] = None
+        push = None
+        if strategy == "esrp":
+            plan = build_plan(problem.a, part, phi)   # static, verified φ+1 copies
+            if failure_runtime is not None:
+                # device-resident redundancy: the storage pushes physically
+                # place each node's p-tiles on the designated holder devices
+                failure_runtime.bind_plan(plan)
+                push = failure_runtime.queue_push
+        dot = getattr(ops, "dot", None)
+
+        if strategy == "imcr":
+            st = imcr.imcr_init(matvec, precond, b, dot=dot)
+            run = lambda s, n: imcr.run_chunk(s, ops, T, phi,
+                                              part.rows_per_node, n,
+                                              thresh_dev, gated, mtr)
+        elif strategy == "esrp":
+            st = esrp.esrp_init(matvec, precond, b, dot=dot, n_slabs=qsum_slabs)
+            if failure_runtime is not None:
+                st = failure_runtime.init_queue(st)
+            run_chk = lambda s, n, chk: esrp.run_chunk(
+                s, ops, T, n, thresh_dev, rr_every, gated, b, push, mtr, chk)
+            run = lambda s, n: run_chk(s, n, sdc_policy if sdc_guard else None)
+        elif strategy == "none":
+            st = esrp.esrp_init(matvec, precond, b, dot=dot)  # T=max: no stores
+            run_chk = lambda s, n, chk: esrp.run_chunk(
+                s, ops, 1 << 30, n, thresh_dev, rr_every, gated, b, None, mtr,
+                chk)
+            run = lambda s, n: run_chk(s, n, sdc_policy if sdc_guard else None)
         else:
-            ops = problem.solver_ops(backend, batch=nbatch,
-                                     fused=batched and batch_fused)
-    matvec = ops.matvec
-    precond = ops.precond
-    b = rhs_arr if rhs_arr is not None else problem.b
-    bnorm = float(jnp.linalg.norm(b))
-    if batched:
-        # per-member threshold. Zero-RHS members (micro-batch padding) get
-        # +inf: their rnorm == 0 row freezes at iteration 0 instead of
-        # dividing by zero, and their report carries rel = 0 / converged
-        bnorm_v = _vec_norm(b)
-        thresh_dev = jnp.where(bnorm_v > 0, rtol * bnorm_v,
-                               jnp.inf).astype(b.dtype)
-        thresh = np.asarray(thresh_dev)        # (B,) host copy, same values
-        conv_iter = np.full(nbatch, -1, np.int64)   # per-member first
-        #                                  crossing (set-once, absolute count)
-    else:
-        thresh_dev = jnp.asarray(rtol * bnorm, b.dtype)
-        # host-side scans must compare against the *same* value the chunk
-        # runner's freeze uses, or (in f32) a norm between the two would
-        # freeze the device state without the host ever declaring convergence
-        thresh = float(thresh_dev)
+            raise ValueError(strategy)
 
-    tr: Optional[Tracer] = obs if isinstance(obs, Tracer) else (
-        Tracer("solve_resilient") if obs else None)
-    mtr = tr is not None              # static: arms the chunk metrics ring
+        event_reports: list[EventReport] = []
+        recovery_s = 0.0
+        wasted = 0
+        target = -1       # "no reconstruction point": restart or no event at all
+        inner_rel = float("nan")
+        # rr gating applies to the esrp/none runners only; imcr's chunk runner
+        # has no replacement gate, so its resume must not add one either
+        resume_rr = rr_every if strategy != "imcr" else 0
 
-    plan: Optional[RedundancyPlan] = None
-    push = None
-    if strategy == "esrp":
-        plan = build_plan(problem.a, part, phi)   # static, verified φ+1 copies
-        if failure_runtime is not None:
-            # device-resident redundancy: the storage pushes physically
-            # place each node's p-tiles on the designated holder devices
-            failure_runtime.bind_plan(plan)
-            push = failure_runtime.queue_push
-    dot = getattr(ops, "dot", None)
+        # per-push tier volume: needed live (the settle-time byte counters), not
+        # just in the end-of-run accounting; rebound on elastic re-partition
+        per_push = (tier.push_bytes(plan, part.m, itemsize)
+                    if strategy == "esrp" and plan is not None else 0)
+        if tr is not None:
+            nat0, tot0 = plan.bytes_per_aspmv(itemsize) if plan is not None \
+                else (0, 0)
+            solve_sp.args.update(
+                strategy=strategy, T=T, phi=phi,
+                backend=backend, variant=getattr(ops, "variant", ""),
+                tier=tier.name, m=part.m, n_nodes=part.n_nodes, rtol=rtol,
+                aspmv_natural_bytes=nat0, aspmv_total_bytes=tot0,
+                per_push_bytes=per_push)
 
-    if strategy == "imcr":
-        st = imcr.imcr_init(matvec, precond, b, dot=dot)
-        run = lambda s, n: imcr.run_chunk(s, ops, T, phi,
-                                          part.rows_per_node, n,
-                                          thresh_dev, gated, mtr)
-    elif strategy == "esrp":
-        st = esrp.esrp_init(matvec, precond, b, dot=dot, n_slabs=qsum_slabs)
-        if failure_runtime is not None:
-            st = failure_runtime.init_queue(st)
-        run_chk = lambda s, n, chk: esrp.run_chunk(
-            s, ops, T, n, thresh_dev, rr_every, gated, b, push, mtr, chk)
-        run = lambda s, n: run_chk(s, n, sdc_policy if sdc_guard else None)
-    elif strategy == "none":
-        st = esrp.esrp_init(matvec, precond, b, dot=dot)  # T=max: no stores
-        run_chk = lambda s, n, chk: esrp.run_chunk(
-            s, ops, 1 << 30, n, thresh_dev, rr_every, gated, b, None, mtr,
-            chk)
-        run = lambda s, n: run_chk(s, n, sdc_policy if sdc_guard else None)
-    else:
-        raise ValueError(strategy)
-
-    event_reports: list[EventReport] = []
-    recovery_s = 0.0
-    wasted = 0
-    target = -1       # "no reconstruction point": restart or no event at all
-    inner_rel = float("nan")
-    # rr gating applies to the esrp/none runners only; imcr's chunk runner
-    # has no replacement gate, so its resume must not add one either
-    resume_rr = rr_every if strategy != "imcr" else 0
-
-    # per-push tier volume: needed live (the settle-time byte counters), not
-    # just in the end-of-run accounting; rebound on elastic re-partition
-    per_push = (tier.push_bytes(plan, part.m, itemsize)
-                if strategy == "esrp" and plan is not None else 0)
-    solve_sp = None
-    if tr is not None:
-        # roofline attribution of the dispatched kernels, priced once per
-        # (backend, variant, shape) at build time and attached to the trace
-        # metadata — the analyzer runs over lowered HLO, no execution
-        tr.meta.setdefault("rooflines", {}).update(
-            _solver_rooflines_cached(problem, ops, b, backend))
-        nat0, tot0 = plan.bytes_per_aspmv(itemsize) if plan is not None \
-            else (0, 0)
-        solve_sp = tr.begin(
-            "solve", cat="solver", strategy=strategy, T=T, phi=phi,
-            backend=backend, variant=getattr(ops, "variant", ""),
-            tier=tier.name, m=part.m, n_nodes=part.n_nodes, rtol=rtol,
-            aspmv_natural_bytes=nat0, aspmv_total_bytes=tot0,
-            per_push_bytes=per_push)
-
-    t0 = time.perf_counter()
-    total_iters = 0
-    run_calls = 0
-    resume_numeric_only = False
-    converged = False
-    sdc_checks = 0
-    sdc_repairs = 0
-    # injections whose corruption no repair has cleared yet, for the
-    # detection-latency attribution: (injection iter, target)
-    sdc_wait: list[tuple[int, str]] = []
-    # iteration stretches actually executed (rollback re-executes, so pushes
-    # re-happen) — the tier push accounting replays the storage schedule
-    # over them after the run
-    push_ranges: list[tuple[int, int]] = []
-    # one chunk's norm record kept in flight: (device record, start
-    # iteration, dispatched length, guard armed?). Readback (the host sync)
-    # happens only after the *next* chunk has been dispatched, so device
-    # compute and host bookkeeping overlap.
-    inflight: Optional[tuple] = None
-    # iteration count the on-device SDC guard halted at (-1 = no halt
-    # pending); set by settle(), consumed by the main loop's check handler
-    halt_iter = -1
-    # armed when the device guard halted but the host check found nothing
-    # (threshold-edge disagreement): the next dispatch steps one iteration
-    # guard-free so the run cannot spin on the same boundary
-    guard_skip = False
+        total_iters = 0
+        run_calls = 0
+        resume_numeric_only = False
+        converged = False
+        sdc_checks = 0
+        sdc_repairs = 0
+        # injections whose corruption no repair has cleared yet, for the
+        # detection-latency attribution: (injection iter, target)
+        sdc_wait: list[tuple[int, str]] = []
+        # iteration stretches actually executed (rollback re-executes, so pushes
+        # re-happen) — the tier push accounting replays the storage schedule
+        # over them after the run
+        push_ranges: list[tuple[int, int]] = []
+        # one chunk's norm record kept in flight: (device record, start
+        # iteration, dispatched length, guard armed?). Readback (the host sync)
+        # happens only after the *next* chunk has been dispatched, so device
+        # compute and host bookkeeping overlap.
+        inflight: Optional[tuple] = None
+        # iteration count the on-device SDC guard halted at (-1 = no halt
+        # pending); set by settle(), consumed by the main loop's check handler
+        halt_iter = -1
+        # armed when the device guard halted but the host check found nothing
+        # (threshold-edge disagreement): the next dispatch steps one iteration
+        # guard-free so the run cannot spin on the same boundary
+        guard_skip = False
+        # the last event whose resume step is still to come: (its report, its
+        # span), so that the report's event_s can run on to that step's end
+        resumed: Optional[tuple] = None
 
     def settle(entry) -> bool:
         """Block on one chunk's norm record; True iff it converged. The
@@ -492,7 +513,7 @@ def solve_resilient(
                 (record[0], None, record[1])
         else:
             norms_d, aux_d = record if mtr else (record, None)
-        with _tspan(tr, "chunk_settle", base=base, n=n_disp):
+        with span("chunk_settle", "solver", tr, base=base, n=n_disp):
             norms = np.asarray(norms_d)
             h_rel = -1
             if halt_d is not None:
@@ -551,15 +572,14 @@ def solve_resilient(
                                    pushes=n_push, tier=tier.name)
         return converged
 
-    try:
-      while not converged:
+    while not converged:
         if resume_numeric_only:
             # post-recovery: re-run the reconstruction-point iteration without
             # its storage prelude (its push already happened pre-failure) but
             # WITH the rr_every replacement gate (see _resume_step). Jitted so
             # the jnp backend fuses exactly like inside run_chunk — keeps the
             # cross-backend trajectory bit-identity through recovery.
-            with _tspan(tr, "resume_step", iter=total_iters):
+            with span("resume_step", "solver", tr, iter=total_iters) as rs_sp:
                 pcg_old = st.pcg
                 pcg = _resume_step(pcg_old, ops, b, resume_rr, gated)
                 if batched:
@@ -579,21 +599,23 @@ def solve_resilient(
                     rnorm = float(rnorm_v.max())
                     if tr is not None:
                         tr.record_iters([total_iters - 1], rnorm=[rnorm])
-                    if bool((rnorm_v < thresh).all()):
-                        converged = True
-                        break
-                    continue
-                rnorm = float(jnp.linalg.norm(pcg.r))
-                if tr is not None:
-                    # the re-run iteration's metrics row (the chunk ring
-                    # never sees it); its push/star already happened on the
-                    # pre-failure pass — dedup keeps this later row
-                    tr.record_iters(
-                        [total_iters - 1], rnorm=[rnorm],
-                        rz=[float(pcg.rz)], push=[0.0], star=[0.0],
-                        orth=[float(jnp.abs(pcg.r @ pcg.p - pcg.rz))])
-            if rnorm < thresh:
-                converged = True
+                    converged = bool((rnorm_v < thresh).all())
+                else:
+                    rnorm = float(jnp.linalg.norm(pcg.r))
+                    if tr is not None:
+                        # the re-run iteration's metrics row (the chunk ring
+                        # never sees it); its push/star already happened on
+                        # the pre-failure pass — dedup keeps this later row
+                        tr.record_iters(
+                            [total_iters - 1], rnorm=[rnorm],
+                            rz=[float(pcg.rz)], push=[0.0], star=[0.0],
+                            orth=[float(jnp.abs(pcg.r @ pcg.p - pcg.rz))])
+                    converged = rnorm < thresh
+            if resumed is not None:
+                ev_rep, ev_sp = resumed
+                ev_rep.event_s = (rs_sp.t1_us - ev_sp.t0_us) / 1e6
+                resumed = None
+            if converged:
                 break
             continue
 
@@ -616,15 +638,15 @@ def solve_resilient(
             # device/host disagreement escape hatch: the guard halted but
             # the authoritative host check found nothing — step exactly one
             # iteration guard-free to move past the boundary
-            with _tspan(tr, "chunk_dispatch", base=total_iters, n=1,
-                        guard_skip=True):
+            with span("chunk_dispatch", "solver", tr, base=total_iters, n=1,
+                      guard_skip=True):
                 st, record = run_chk(st, 1, None)
             run_calls += 1
             entry = (record, total_iters, 1, False)
             total_iters += 1
             guard_skip = False
         elif n > 0:
-            with _tspan(tr, "chunk_dispatch", base=total_iters, n=n):
+            with span("chunk_dispatch", "solver", tr, base=total_iters, n=n):
                 st, record = run(st, n)          # async dispatch
             run_calls += 1
             entry = (record, total_iters, n, sdc_guard)
@@ -680,9 +702,9 @@ def solve_resilient(
                 # corruption struck mid-iteration; nothing stops, nothing is
                 # reported to the solver — only an invariant check can catch
                 # it downstream
-                with _tspan(tr, "event:sdc-inject", cat="event",
-                            iter=ev.iter, nodes=list(ev.nodes),
-                            target=ev.target):
+                with span("event:sdc-inject", "event", tr,
+                          iter=ev.iter, nodes=list(ev.nodes),
+                          target=ev.target):
                     # already-converged members are shielded: their B=1
                     # reference runs ended before the corruption struck, so
                     # neither the injected flip nor the injection
@@ -715,6 +737,7 @@ def solve_resilient(
                 failed = list(ev.nodes)
                 ev_inner = float("nan")
                 ev_pff = -1
+                ev_inner_iters = 0
                 ev_reload = 0
                 ev_src: tuple[int, ...] = ()
                 ev_fetch = 0
@@ -726,9 +749,9 @@ def solve_resilient(
                 st_pre = st if batched else None
                 done_pre = (_vec_norm(st.pcg.r) < thresh_dev) if batched \
                     else None
-                with _tspan(tr, "event:fail-stop", cat="event",
-                            iter=ev.iter, nodes=list(ev.nodes),
-                            strategy=strategy) as ev_sp:
+                with span("event:fail-stop", "event", tr,
+                          iter=ev.iter, nodes=list(ev.nodes),
+                          strategy=strategy) as ev_sp:
                     if strategy == "imcr":
                         st, ev_wasted, target, rec_t = _imcr_failure(
                             st, part, failed, phi, matvec, precond, b,
@@ -741,7 +764,7 @@ def solve_resilient(
                             st, matvec, precond, b, dot=dot)
                     else:
                         (st, ev_wasted, target, ev_inner, rec_t, ev_pff,
-                         ev_reload, ev_src) = _esrp_failure(
+                         ev_reload, ev_src, ev_inner_iters) = _esrp_failure(
                             problem, plan, st, failed, T, ops, pff_precond,
                             fruntime=failure_runtime, push=push,
                             n_slabs=qsum_slabs, b=b, tracer=tr)
@@ -762,6 +785,7 @@ def solve_resilient(
                         iter=ev.iter, nodes=ev.nodes, target_iter=target,
                         wasted_iters=ev_wasted, recovery_s=rec_t,
                         inner_rel=ev_inner, pff_iters=ev_pff,
+                        inner_iters=ev_inner_iters,
                         precond_reload_bytes=ev_reload, queue_src_nodes=ev_src,
                         tier=tier.name, fetch_bytes=ev_fetch,
                         fetch_s_model=ev_fetch_s)
@@ -772,8 +796,8 @@ def solve_resilient(
                         # state extends with exactly-consistent zero padding
                         # rows (core.elastic)
                         n_new = part.n_nodes - len(ev.nodes)
-                        with _tspan(tr, "elastic_repartition", cat="recovery",
-                                    n_nodes=n_new):
+                        with span("elastic_repartition", "recovery", tr,
+                                  n_nodes=n_new):
                             problem = elastic_mod.shrink_problem(problem, n_new)
                             part = problem.part
                             st = elastic_mod.remap_state(st, part.m,
@@ -817,11 +841,13 @@ def solve_resilient(
                     event_reports.append(er)
                     total_iters = int(st.pcg.j)
                     resume_numeric_only = target >= 0
+                er.event_s = ev_sp.dur_s
+                resumed = (er, ev_sp) if resume_numeric_only else None
 
         if at_check:
             sdc_checks += 1
-            with _tspan(tr, "sdc_check", cat="sdc",
-                        iter=total_iters, from_halt=from_halt) as ck_sp:
+            with span("sdc_check", "sdc", tr,
+                      iter=total_iters, from_halt=from_halt) as ck_sp:
                 # converged members are excluded from detection: their B=1
                 # reference runs already ended, so nothing about them may
                 # fire a repair (zero-RHS padding is excluded inside
@@ -830,8 +856,7 @@ def solve_resilient(
                         if batched else None)
                 det = sdc.run_checks(ops, st, b, part, bnorm, sdc_policy,
                                      live=live)
-                if ck_sp is not None:
-                    ck_sp.args["fired"] = det is not None
+                ck_sp.args["fired"] = det is not None
             if det is None and from_halt:
                 guard_skip = True
             if det is not None:
@@ -861,14 +886,15 @@ def solve_resilient(
                                tol=float(det.tol))
                 ev_inner = float("nan")
                 ev_pff = -1
+                ev_inner_iters = 0
                 rec_t = 0.0
                 ev_wasted = 0
                 ev_src = ()
                 ev_fetch = 0
                 ev_fetch_s = 0.0
-                with _tspan(tr, "event:sdc-repair", cat="event", iter=J,
-                            detector=det.detector,
-                            nodes=list(det.flagged)) as rp_sp:
+                with span("event:sdc-repair", "event", tr, iter=J,
+                          detector=det.detector,
+                          nodes=list(det.flagged)) as rp_sp:
                     # converged members are shielded from the rollback
                     # (their reference runs ended before this repair);
                     # queue invalidation is shared bookkeeping (slot axis)
@@ -897,7 +923,7 @@ def solve_resilient(
                         ev_wasted, target = J, -1
                     else:
                         (st, ev_wasted, target, ev_inner, rec_t, ev_pff, _,
-                         ev_src) = _esrp_failure(
+                         ev_src, ev_inner_iters) = _esrp_failure(
                             problem, plan, st, list(det.flagged), T, ops,
                             pff_precond, fruntime=failure_runtime, push=push,
                             sdc_mode=True, n_slabs=qsum_slabs, b=b,
@@ -919,100 +945,82 @@ def solve_resilient(
                         rp_sp.args.update(target_iter=target,
                                           wasted_iters=ev_wasted,
                                           latency=latency)
-                    event_reports.append(EventReport(
+                    er = EventReport(
                         iter=J, nodes=tuple(det.flagged), target_iter=target,
                         wasted_iters=ev_wasted, recovery_s=rec_t,
                         inner_rel=ev_inner, pff_iters=ev_pff,
+                        inner_iters=ev_inner_iters,
                         queue_src_nodes=ev_src, kind="sdc-repair",
                         detector=det.detector, detect_iter=J,
                         detect_latency=latency, sdc_violation=det.violation,
                         sdc_tol=det.tol, tier=tier.name, fetch_bytes=ev_fetch,
-                        fetch_s_model=ev_fetch_s))
+                        fetch_s_model=ev_fetch_s)
+                    event_reports.append(er)
                     total_iters = int(st.pcg.j)
                     resume_numeric_only = (not want_q) and target >= 0
-    finally:
-        if tr is not None:
-            # close anything an exception unwound past, then the solve span
-            tr.close(solve_sp, converged=converged, iters=total_iters,
-                     recovery_s=recovery_s, wasted_iters=wasted,
-                     run_calls=run_calls)
-    runtime = time.perf_counter() - t0
-
-    pcg = st.pcg
-    jax.block_until_ready(pcg.x)
-    nat_bytes = tot_bytes = 0
-    if plan is not None:
-        nat_bytes, tot_bytes = plan.bytes_per_aspmv(itemsize)
-    push_count = 0
-    if strategy == "esrp" and plan is not None:
-        push_count = _count_pushes(push_ranges, T)
-    if sdc_guard:
-        # the guard evaluated one on-device check at every boundary the
-        # executed stretches crossed; host confirmations (halts, post-inject
-        # checks) were counted live into sdc_checks above
-        sdc_checks += _count_checks(push_ranges, sdc_policy.check_every, T,
-                                    strategy == "esrp")
-    common = dict(
-        strategy=strategy, T=T, phi=phi, runtime_s=runtime,
-        recovery_s=recovery_s, wasted_iters=wasted, target_iter=target,
-        inner_rel=inner_rel, aspmv_natural_bytes=nat_bytes,
-        aspmv_total_bytes=tot_bytes, run_calls=run_calls,
-        events=event_reports,
-        precond_variant=getattr(ops, "variant", ""),
-        precond_reload_bytes=sum(e.precond_reload_bytes
-                                 for e in event_reports),
-        tier=tier.name, push_count=push_count,
-        push_bytes=push_count * per_push,
-        push_s_model=push_count * (tier.write_s(per_push) if per_push
-                                   else 0.0),
-        fetch_s_model=sum(e.fetch_s_model for e in event_reports),
-        sdc_checks=sdc_checks,
-        sdc_check_every=sdc_policy.check_every if sdc_on else 0,
-        final_n_nodes=part.n_nodes, trace=tr)
-    if not batched:
-        drift = float(residual_drift(matvec, b, pcg.x, pcg.r))
-        rel = float(jnp.linalg.norm(pcg.r)) / float(jnp.linalg.norm(b))
-        report = SolveReport(converged_iter=total_iters, rel_residual=rel,
-                             drift=drift, converged=converged, x=pcg.x,
-                             **common)
-        if tr is not None:
-            tr.record("solve_report", report.to_json())
-        return report
-    # batched: one SolveReport per member. Shared run accounting (runtime,
-    # events, tier/push totals) repeats on every member — per-member fields
-    # are the convergence count, residuals, drift, and the iterate itself.
-    rel_v = np.asarray(_vec_norm(pcg.r))
-    bn_v = np.asarray(_vec_norm(b))
-    drift_v = np.asarray(residual_drift(matvec, b, pcg.x, pcg.r))
-    reports = []
-    for k in range(nbatch):
-        ok = conv_iter[k] >= 0
-        reports.append(SolveReport(
-            converged_iter=int(conv_iter[k]) if ok else total_iters,
-            rel_residual=(float(rel_v[k] / bn_v[k]) if bn_v[k] > 0 else 0.0),
-            drift=float(drift_v[k]), converged=bool(ok or converged),
-            batch_index=k, batch_size=nbatch, x=pcg.x[k], **common))
+                er.event_s = rp_sp.dur_s
+                resumed = (er, rp_sp) if resume_numeric_only else None
     if tr is not None:
-        for r in reports:
-            tr.record("solve_report", r.to_json())
-    return reports
+        solve_sp.args.update(converged=converged, iters=total_iters,
+                             recovery_s=recovery_s, wasted_iters=wasted,
+                             run_calls=run_calls)
 
-
-def _solver_rooflines_cached(problem: Problem, ops, b, backend: str) -> dict:
-    """Roofline attribution of the SolverOps kernels, cached on the problem
-    per (backend, variant, shape, dtype) — the HLO lowering+analysis runs
-    once per distinct compiled program, like the jitted runners themselves."""
-    from repro.obs.rooflines import solver_rooflines
-
-    cache = getattr(problem, "_roofline_cache", None)
-    if cache is None:
-        cache = {}
-        problem._roofline_cache = cache
-    key = (backend, getattr(ops, "variant", ""), tuple(np.shape(b)),
-           str(np.dtype(b.dtype)))
-    if key not in cache:
-        cache[key] = solver_rooflines(ops, b)
-    return cache[key]
+    with span("epilogue", "solver", tr):
+        pcg = st.pcg
+        jax.block_until_ready(pcg.x)
+        nat_bytes = tot_bytes = 0
+        if plan is not None:
+            nat_bytes, tot_bytes = plan.bytes_per_aspmv(itemsize)
+        push_count = 0
+        if strategy == "esrp" and plan is not None:
+            push_count = _count_pushes(push_ranges, T)
+        if sdc_guard:
+            # the guard evaluated one on-device check at every boundary the
+            # executed stretches crossed; host confirmations (halts, post-inject
+            # checks) were counted live into sdc_checks above
+            sdc_checks += _count_checks(push_ranges, sdc_policy.check_every, T,
+                                        strategy == "esrp")
+        common = dict(
+            strategy=strategy, T=T, phi=phi,
+            runtime_s=0.0,                 # _in_solve_span sets it once closed
+            prologue_s=pro_sp.dur_s,
+            recovery_s=recovery_s, wasted_iters=wasted, target_iter=target,
+            inner_rel=inner_rel, aspmv_natural_bytes=nat_bytes,
+            aspmv_total_bytes=tot_bytes, run_calls=run_calls,
+            events=event_reports,
+            precond_variant=getattr(ops, "variant", ""),
+            precond_reload_bytes=sum(e.precond_reload_bytes
+                                     for e in event_reports),
+            tier=tier.name, push_count=push_count,
+            push_bytes=push_count * per_push,
+            push_s_model=push_count * (tier.write_s(per_push) if per_push
+                                       else 0.0),
+            fetch_s_model=sum(e.fetch_s_model for e in event_reports),
+            sdc_checks=sdc_checks,
+            sdc_check_every=sdc_policy.check_every if sdc_on else 0,
+            final_n_nodes=part.n_nodes, trace=tr)
+        if not batched:
+            drift = float(residual_drift(matvec, b, pcg.x, pcg.r))
+            rel = float(jnp.linalg.norm(pcg.r)) / float(jnp.linalg.norm(b))
+            report = SolveReport(converged_iter=total_iters, rel_residual=rel,
+                                 drift=drift, converged=converged, x=pcg.x,
+                                 **common)
+            return report
+        # batched: one SolveReport per member. Shared run accounting (runtime,
+        # events, tier/push totals) repeats on every member — per-member fields
+        # are the convergence count, residuals, drift, and the iterate itself.
+        rel_v = np.asarray(_vec_norm(pcg.r))
+        bn_v = np.asarray(_vec_norm(b))
+        drift_v = np.asarray(residual_drift(matvec, b, pcg.x, pcg.r))
+        reports = []
+        for k in range(nbatch):
+            ok = conv_iter[k] >= 0
+            reports.append(SolveReport(
+                converged_iter=int(conv_iter[k]) if ok else total_iters,
+                rel_residual=(float(rel_v[k] / bn_v[k]) if bn_v[k] > 0 else 0.0),
+                drift=float(drift_v[k]), converged=bool(ok or converged),
+                batch_index=k, batch_size=nbatch, x=pcg.x[k], **common))
+        return reports
 
 
 # --------------------------------------------------------------------------- #
@@ -1158,12 +1166,13 @@ def _esrp_failure(problem: Problem, plan: RedundancyPlan, st: esrp.ESRPState,
     b_rhs = problem.b if b is None else b
     J = int(st.pcg.j)
     if not sdc_mode:
-        st = jax.jit(esrp.esrp_prelude, static_argnums=(1, 2, 3))(st, T,
-                                                                  True, push)
+        with span("prelude", "recovery", tracer):
+            st = jax.jit(esrp.esrp_prelude, static_argnums=(1, 2, 3))(
+                st, T, True, push)
 
     # --- the failure: all dynamic data on failed nodes is lost -------------
-    with _tspan(tracer, "inject", cat="recovery", nodes=list(failed),
-                sdc_mode=sdc_mode):
+    with span("inject", "recovery", tracer, nodes=list(failed),
+              sdc_mode=sdc_mode):
         if sdc_mode and fruntime is not None:
             st = fruntime.lose_live(st, failed)
             reload_bytes = 0
@@ -1195,7 +1204,7 @@ def _esrp_failure(problem: Problem, plan: RedundancyPlan, st: esrp.ESRPState,
                              n_slabs=n_slabs)
         if fruntime is not None:
             st2 = fruntime.init_queue(st2, reset=True)
-        return st2, J, -1, float("nan"), 0.0, -1, reload_bytes, ()
+        return st2, J, -1, float("nan"), 0.0, -1, reload_bytes, (), 0
 
     if T == 1:
         # ESR: no rollback — reconstruct the *live* iteration J from the
@@ -1221,15 +1230,15 @@ def _esrp_failure(problem: Problem, plan: RedundancyPlan, st: esrp.ESRPState,
     fetch_bytes = 2 * len(failed) * part.rows_per_node * \
         np.dtype(problem.b.dtype).itemsize * \
         (b_rhs.shape[0] if b_rhs.ndim == 2 else 1)
-    with _tspan(tracer, "queue_fetch", cat="recovery",
-                slots=[int(prev_slot), int(curr_slot)],
-                bytes=int(fetch_bytes)) as qf_sp:
+    with span("queue_fetch", "recovery", tracer,
+              slots=[int(prev_slot), int(curr_slot)],
+              bytes=int(fetch_bytes)) as qf_sp:
         if fruntime is not None and not sdc_mode:
             p_prev, p_curr, src_nodes = fruntime.assemble_pair(
                 st, prev_slot, curr_slot, failed)
         else:
             p_prev, p_curr, src_nodes = st.q[prev_slot], st.q[curr_slot], ()
-        if qf_sp is not None:
+        if tracer is not None:
             jax.block_until_ready(p_curr)
             qf_sp.args["sources"] = list(src_nodes)
 
@@ -1243,8 +1252,8 @@ def _esrp_failure(problem: Problem, plan: RedundancyPlan, st: esrp.ESRPState,
         problem._recon_cache = cache
     key = (tuple(failed), pff_precond)
     if key not in cache:
-        with _tspan(tracer, "reconstruction_build", cat="build",
-                    nodes=list(failed), pff_precond=pff_precond):
+        with span("reconstruction_build", "build", tracer,
+                  nodes=list(failed), pff_precond=pff_precond):
             ops = esr.ReconstructionOps.build(problem, failed,
                                               pff_precond=pff_precond)
             bf_warm = (None if b is None
@@ -1257,18 +1266,19 @@ def _esrp_failure(problem: Problem, plan: RedundancyPlan, st: esrp.ESRPState,
         cache[key] = ops
     ops = cache[key]
     b_f = None if b is None else b_rhs[..., jnp.asarray(ops.f_rows)]
-    t0 = time.perf_counter()
-    x_f, r_f, z_f, inner_rel = esr.reconstruct(
-        ops, p_prev=p_prev, p_curr=p_curr, beta_prev=beta_prev,
-        r_surv=r_surv, x_surv=x_surv, b_f=b_f, tracer=tracer)
-    with _tspan(tracer, "scatter", cat="recovery", target_iter=target):
-        f_rows = jnp.asarray(ops.f_rows)
-        x = x_surv.at[..., f_rows].set(x_f)
-        r = r_surv.at[..., f_rows].set(r_f)
-        z = z_surv.at[..., f_rows].set(z_f)
-        p = p_surv.at[..., f_rows].set(p_curr[..., f_rows])
-        jax.block_until_ready(x)
-    rec_t = time.perf_counter() - t0
+    # recovery_s: Alg. 2 and the scatter, up to the rebuilt x on the device
+    with span("reconstruct", "recovery", tracer,
+              target_iter=target) as rec_sp:
+        x_f, r_f, z_f, inner_rel, inner_iters = esr.reconstruct(
+            ops, p_prev=p_prev, p_curr=p_curr, beta_prev=beta_prev,
+            r_surv=r_surv, x_surv=x_surv, b_f=b_f, tracer=tracer)
+        with span("scatter", "recovery", tracer, target_iter=target):
+            f_rows = jnp.asarray(ops.f_rows)
+            x = x_surv.at[..., f_rows].set(x_f)
+            r = r_surv.at[..., f_rows].set(r_f)
+            z = z_surv.at[..., f_rows].set(z_f)
+            p = p_surv.at[..., f_rows].set(p_curr[..., f_rows])
+            jax.block_until_ready(x)
 
     new_pcg = PCGState(x=x, r=r, z=z, p=p, rz=rz, beta=beta_prev,
                        j=jnp.asarray(target, jnp.int32))
@@ -1312,8 +1322,10 @@ def _esrp_failure(problem: Problem, plan: RedundancyPlan, st: esrp.ESRPState,
     pff_stats = getattr(ops.p_solve, "stats", None) if ops.p_solve else None
     pff_iters = pff_stats["iters"] if pff_stats else -1
     # batched line-8 rel is per-member — report the worst one
-    return (st2, J - target, target, float(np.max(np.asarray(inner_rel))),
-            rec_t, pff_iters, reload_bytes, src_nodes)
+    inner_rel, inner_iters = jax.device_get((inner_rel, inner_iters))
+    return (st2, J - target, target, float(np.max(inner_rel)),
+            rec_sp.dur_s, pff_iters, reload_bytes, src_nodes,
+            int(np.max(inner_iters)))
 
 
 def _imcr_failure(st: imcr.IMCRState, part, failed: list[int], phi: int,
@@ -1341,10 +1353,8 @@ def _imcr_failure(st: imcr.IMCRState, part, failed: list[int], phi: int,
     tag = int(st.ck_tag)
     if tag < 0:                      # failure before the first checkpoint
         return imcr.imcr_init(matvec, precond, b, dot=dot), J, -1, 0.0
-    t0 = time.perf_counter()
-    with _tspan(tracer, "buddy_restore", cat="recovery", tag=tag,
-                nodes=list(failed)):
+    with span("buddy_restore", "recovery", tracer, tag=tag,
+              nodes=list(failed)) as rs_sp:
         pcg = imcr.recover(st)       # fetch-from-buddy (restore the copies)
         jax.block_until_ready(pcg.x)
-    rec_t = time.perf_counter() - t0
-    return st._replace(pcg=pcg), J - tag, tag, rec_t
+    return st._replace(pcg=pcg), J - tag, tag, rs_sp.dur_s

@@ -40,7 +40,6 @@ r, x, so either source yields the same algebra.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from functools import partial
 
@@ -51,6 +50,7 @@ import numpy as np
 from repro.core import failures
 from repro.core.ops import SolverOps, bound, jacobi_apply, make_closure_ops
 from repro.core.pcg import _expand, solve_pcg, solve_pcg_batched
+from repro.obs.trace import span
 from repro.sparse.blockell import BlockEll, ell_matvec
 from repro.sparse.matrices import Problem
 
@@ -149,13 +149,6 @@ def _inner_ops(operands) -> SolverOps:
                             lambda r: jacobi_apply(pinv, r))
 
 
-def _span(tracer, name: str, **args):
-    """Recovery-phase span, or a no-op context when observability is off."""
-    if tracer is None:
-        return contextlib.nullcontext()
-    return tracer.span(name, cat="recovery", **args)
-
-
 def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
                 beta_prev: jax.Array, r_surv: jax.Array, x_surv: jax.Array,
                 inner_rtol: float | None = None,
@@ -163,14 +156,16 @@ def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
                 b_f: jax.Array | None = None, tracer=None):
     """Run Alg. 2. Inputs are full-length vectors; only surviving (resp.
     redundant-copy) entries are read, enforced by masking. Returns the failed
-    nodes' compact (x_f, r_f, z_f) plus the inner-solve relative residual.
+    nodes' compact (x_f, r_f, z_f), the line-8 inner solve's relative
+    residual and its iteration count (device scalars).
 
-    ``tracer`` (an obs.Tracer, or None) gets one nested span per Alg. 2
-    phase — lines 4-5 (off-diagonal apply), the line-6 P_ff solve, the
-    line-7 RHS assembly, the line-8 A_ff solve. Per-phase wall times are
-    only meaningful with a host sync at each boundary, so the spans
-    block_until_ready their segment's outputs; tracer=None skips both the
-    spans and the syncs (the default async hot path is untouched).
+    Each Alg. 2 phase is an ``obs.span`` — lines 4-5 (off-diagonal apply),
+    the line-6 P_ff solve, the line-7 RHS assembly, the line-8 A_ff solve.
+    Per-phase wall times are only meaningful with a host sync at each
+    boundary, so with a ``tracer`` (an obs.Tracer) the spans
+    block_until_ready their segment's outputs; with tracer=None nothing
+    syncs and the spans time the host's dispatch (the default async hot
+    path is untouched).
 
     Batch-polymorphic: (B, M) vector inputs with (B,) ``beta_prev`` run ONE
     Alg. 2 pass over the shared f-slab for all B members — the static strip
@@ -202,9 +197,10 @@ def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
             "only (preconditioners with off-diagonal coupling pend)")
 
     itemsize = np.dtype(r_surv.dtype).itemsize
-    with _span(tracer, "alg2_line5_offdiag", n_failed_rows=int(ops.f_rows.size),
-               bytes=int((ops.f_rows.size + r_surv.size) * itemsize),
-               jacobi_closed_form=ops.p_solve is None):
+    with span("alg2_line5_offdiag", "recovery", tracer,
+              n_failed_rows=int(ops.f_rows.size),
+              bytes=int((ops.f_rows.size + r_surv.size) * itemsize),
+              jacobi_closed_form=ops.p_solve is None):
         p_prev_f = p_prev[..., f_rows]
         p_curr_f = p_curr[..., f_rows]
         z_f = p_curr_f - _expand(beta_prev, p_curr_f) * p_prev_f  # line 4
@@ -216,8 +212,8 @@ def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
             # the surviving entries (the closure masks I_f)
             v = sync(z_f - ops.p_offdiag(r_surv))               # line 5
 
-    with _span(tracer, "alg2_line6_pff_solve",
-               jacobi_closed_form=ops.p_solve is None) as sp6:
+    with span("alg2_line6_pff_solve", "recovery", tracer,
+              jacobi_closed_form=ops.p_solve is None) as sp6:
         if ops.p_solve is None:
             # block-Jacobi closed form: P_ff^{-1} = A_bb, one block matvec
             # (per member when batched — keeps the scalar subgraph exact)
@@ -231,11 +227,11 @@ def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
             # real local P_ff solve through the preconditioner's kernels
             r_f = sync(ops.p_solve(v, inner_rtol, inner_max_iters))  # line 6
             stats = getattr(ops.p_solve, "stats", None)
-            if sp6 is not None and stats:
+            if tracer is not None and stats:
                 sp6.args.update({k: jsonable_stat(v2)
                                  for k, v2 in dict(stats).items()})
 
-    with _span(tracer, "alg2_line7_w"):
+    with span("alg2_line7_w", "recovery", tracer):
         x_masked = jnp.where(mask, jnp.zeros_like(x_surv), x_surv)
         if not batched:
             ax = ops.a_rows_f.matvec(x_masked)
@@ -244,17 +240,17 @@ def reconstruct(ops: ReconstructionOps, *, p_prev: jax.Array, p_curr: jax.Array,
                             for i in range(x_masked.shape[0])])
         w = sync((ops.b_f if b_f is None else b_f) - r_f - ax)     # line 7
 
-    with _span(tracer, "alg2_line8_aff_solve",
-               inner_rtol=inner_rtol) as sp8:
+    with span("alg2_line8_aff_solve", "recovery", tracer,
+              inner_rtol=inner_rtol) as sp8:
         solve = solve_pcg if not batched else solve_pcg_batched
         state, rel = solve(ops.inner, w, inner_rtol,
                            inner_max_iters)                        # line 8
         x_f = sync(state.x)
-        if sp8 is not None:
+        if tracer is not None:
             sp8.args["inner_rel"] = (float(rel) if not batched
                                      else float(np.max(np.asarray(rel))))
             sp8.args["inner_iters"] = int(state.j)
-    return x_f, r_f, z_f, rel
+    return x_f, r_f, z_f, rel, state.j
 
 
 def jsonable_stat(v):

@@ -33,7 +33,6 @@ replicated scalars, the step counter survives on any rank).
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Callable, NamedTuple, Optional
@@ -42,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.ft.buddy import BuddyPlan
+from repro.obs.trace import span
 from repro.train.optimizer import OptState
 
 
@@ -86,11 +86,6 @@ class ESRPTrainer:
         self.obs = obs                # obs.Tracer: storage/failure/recovery
         #                               spans + per-step loss counter
 
-    def _span(self, name: str, cat: str, **args):
-        if self.obs is None:
-            return contextlib.nullcontext()
-        return self.obs.span(name, cat=cat, **args)
-
     # ------------------------------------------------------------------ #
     def init_buffers(self, params, opt: OptState) -> FTBuffers:
         self._plan = BuddyPlan.build(params, self.param_specs,
@@ -109,8 +104,8 @@ class ESRPTrainer:
         paper's starred duplicates, no communication)."""
         if self.ft.mode == "none":
             return bufs
-        with self._span("ft_storage_push", cat="storage", step=step,
-                        mode=self.ft.mode) as push_sp:
+        with span("ft_storage_push", "storage", self.obs, step=step,
+                  mode=self.ft.mode) as push_sp:
             dtype = jnp.bfloat16 if self.ft.compress else None
             p_copies = self._plan.push(params)     # esrp: retained, not sent
             mu_copies = self._mplan.push(opt.mu, dtype)
@@ -134,8 +129,8 @@ class ESRPTrainer:
                 pushed += self._plan.bytes_per_push(params)
             self.push_bytes += pushed
             self.push_count += 1
-            if push_sp is not None:
-                push_sp.args["bytes"] = pushed
+            push_sp.args["bytes"] = pushed
+            if self.obs is not None:
                 self.obs.add_counter("ft_push_bytes", pushed, step=step)
         return FTBuffers(sl, sp, sm, sn, ss, active=slot)
 
@@ -206,15 +201,14 @@ class ESRPTrainer:
             if pending and step == pending[0].iter:
                 ev = pending.pop(0)
                 failed = list(ev.nodes)
-                with self._span("ft_inject", cat="event", step=step,
-                                ranks=failed):
+                with span("ft_inject", "event", self.obs, step=step,
+                          ranks=failed):
                     params, opt, bufs = self.inject_failure(params, opt,
                                                             bufs, failed)
-                with self._span("ft_recover", cat="recovery",
-                                ranks=failed) as rec_sp:
+                with span("ft_recover", "recovery", self.obs,
+                          ranks=failed) as rec_sp:
                     params, opt, step = self.recover(bufs, failed)
-                    if rec_sp is not None:
-                        rec_sp.args["restart_step"] = step
+                    rec_sp.args["restart_step"] = step
                 continue
             batch = self.pipeline.batch_at(step)
             params, opt, metrics = self.train_step(params, opt, batch)

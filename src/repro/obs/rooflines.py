@@ -8,8 +8,8 @@ kernels (SpMV, fused SpMV+dot, preconditioner apply, fused update, and the
 whole PCG iteration) against shape-only abstract inputs, runs the while-aware
 cost analyzer over the compiled text, and returns FLOP / HBM-byte /
 collective-byte counts plus the FLOP/byte arithmetic intensity per kernel.
-The driver attaches the result to the trace metadata (``Tracer.meta``) and
-``benchmarks/run.py`` embeds it in BENCH_failures.json (CI fails if absent).
+``benchmarks/run.py`` embeds the result in its BENCH_*.json (CI fails if
+absent).
 
 Costs are analyzer-model numbers over the *post-optimization* HLO of the
 current backend — a per-program traffic floor for relative comparison, not a

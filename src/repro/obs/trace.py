@@ -22,6 +22,11 @@ Conventions:
 ``jsonable`` is the single serialization path shared by the trace exporters,
 the JSONL event log, and the report ``to_json`` methods (driver satellite):
 device/numpy scalars coerce to Python, arrays to lists, NaN/inf to None.
+
+``span`` is the one way the program marks a phase: always a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (so a profile shows
+the phase on the host thread, on the profiler's clock beside the device
+ops), and a ``Tracer`` span too when one is attached.
 """
 from __future__ import annotations
 
@@ -29,6 +34,7 @@ import contextlib
 import math
 import time
 
+import jax
 import numpy as np
 
 SCHEMA_VERSION = 1
@@ -134,6 +140,11 @@ class Tracer:
         finally:
             self.close(sp)
 
+    @property
+    def current(self) -> Span | None:
+        """The innermost open span."""
+        return self._stack[-1] if self._stack else None
+
     # -- points -------------------------------------------------------- #
     def instant(self, name: str, cat: str = "solver", **args) -> None:
         self.events.append(dict(name=name, cat=cat, ph="i", s="t",
@@ -198,3 +209,32 @@ class Tracer:
             arr = np.asarray(v)
             out[k] = arr[keep] if it.size else arr
         return out
+
+
+@contextlib.contextmanager
+def span(name: str, cat: str = "solver", tracer: Tracer | None = None,
+         **args):
+    """Mark one program phase; yields its ``Span``, whose ``dur_s`` is the
+    phase's host-clock duration once the block has left.
+
+    The phase is always a ``jax.profiler.TraceAnnotation`` named
+    ``repro.<name>``: free unless a profiler is recording, and then a host
+    span on the profiler's clock, on the thread that ran it. With a
+    ``tracer`` it is also a span of that Tracer, under ``name`` with
+    ``args``. Without one nothing else happens: ``args`` are kept on the
+    yielded Span and never serialized, its ``t0_us``/``t1_us`` are
+    ``time.perf_counter`` microseconds, and nothing waits on the device. So
+    a phase that only dispatches device work times the host's dispatch,
+    not the device; callers that want device time either end the phase on
+    a read-back or sync only when a tracer is attached.
+    """
+    with jax.profiler.TraceAnnotation("repro." + name):
+        if tracer is not None:
+            with tracer.span(name, cat, **args) as sp:
+                yield sp
+            return
+        sp = Span(name, cat, args, time.perf_counter() * 1e6)
+        try:
+            yield sp
+        finally:
+            sp.t1_us = time.perf_counter() * 1e6

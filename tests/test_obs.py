@@ -12,12 +12,16 @@ The contract under test:
     invariant-noise floor);
   * the span tree is well-formed: every recovery phase nests under its
     fail-stop event span, byte counters are populated from the tier cost
-    model, rooflines price the dispatched kernels, and the exported
-    Chrome-trace passes the validator + file round-trips;
+    model, and the exported Chrome-trace passes the validator + file
+    round-trips;
+  * the program's spans are in a ``jax.profiler`` trace with no Tracer
+    attached, nested on one host thread around the device work, and the
+    report counters read from them agree with the Tracer's spans;
   * SolveReport/EventReport.to_json is a JSON-safe, schema-versioned dict
     (no device arrays, no NaN) — the BENCH writers' serialization path.
 """
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -28,9 +32,9 @@ from repro.analysis import assert_structurally_equal
 from repro.core import esrp, imcr
 from repro.core.driver import REPORT_SCHEMA_VERSION, solve_resilient
 from repro.core.failures import FailureEvent
-from repro.obs import (Tracer, chrome_trace, metrics_snapshot, span_tree,
-                       validate_chrome_trace, walk_spans, write_chrome_trace,
-                       write_jsonl)
+from repro.obs import (Tracer, chrome_trace, metrics_snapshot, span,
+                       span_tree, validate_chrome_trace, walk_spans,
+                       write_chrome_trace, write_jsonl)
 from repro.sparse.matrices import build_problem
 
 
@@ -215,16 +219,121 @@ def test_trace_validates_and_events_nest(traced):
     assert all("iter" in e["args"] and "rnorm" in e["args"] for e in its)
 
 
-def test_rooflines_attached(traced):
-    rf = traced.trace.meta.get("rooflines", {})
-    priced = [k for k, v in rf.items()
-              if isinstance(v, dict) and "error" not in v
-              and isinstance(v.get("flops"), (int, float)) and v["flops"] > 0
-              and v.get("hbm_bytes", 0) > 0]
-    assert len(priced) >= 3, rf.keys()
-    for k in priced:
-        assert rf[k]["flop_per_byte"] == pytest.approx(
-            rf[k]["flops"] / rf[k]["hbm_bytes"])
+# --------------------------------------------------------------------------- #
+# program spans on the profiler's clock, and the report counters read from them
+# --------------------------------------------------------------------------- #
+FAILSTOP = dict(strategy="esrp", T=10, phi=1, rtol=1e-8,
+                scenario=[FailureEvent(iter=28, nodes=(1,))])
+
+
+def _host_spans(profile):
+    """{thread line: [(name, start_ns, end_ns)]} of the host plane's
+    ``repro.*`` spans, and every XLA:CPU op interval."""
+    spans, ops = {}, []
+    for plane in profile.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                iv = (e.start_ns, e.start_ns + e.duration_ns)
+                if e.name.startswith("repro."):
+                    spans.setdefault(line.name, []).append((e.name, *iv))
+                elif line.name.startswith("tf_XLAPjRtCpuClient"):
+                    ops.append(iv)
+    return spans, ops
+
+
+def test_program_spans_land_in_a_profile_without_a_tracer(problem, tmp_path):
+    solve_resilient(problem, **FAILSTOP)           # compiles outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        rep = solve_resilient(problem, **FAILSTOP)
+    finally:
+        jax.profiler.stop_trace()
+    assert rep.trace is None and rep.converged
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans, ops = _host_spans(jax.profiler.ProfileData.from_file(str(path)))
+    # every phase on one thread: the one that called the solver
+    (thread,) = [evs for evs in spans.values()
+                 if any(n == "repro.solve" for n, _, _ in evs)]
+    by = {}
+    for name, t0, t1 in thread:
+        by.setdefault(name, []).append((t0, t1))
+    want = {"repro.solve", "repro.prologue", "repro.chunk_settle",
+            "repro.event:fail-stop", "repro.scatter", "repro.epilogue"}
+    assert want <= set(by), sorted(by)
+    ((s0, s1),) = by["repro.solve"]
+    for name, ivs in by.items():
+        for t0, t1 in ivs:
+            assert s0 <= t0 <= t1 <= s1, name
+    ((e0, e1),) = by["repro.event:fail-stop"]
+    ((c0, c1),) = by["repro.scatter"]
+    assert e0 <= c0 <= c1 <= e1
+    ((p0, p1),) = by["repro.prologue"]
+    assert p1 <= min(t0 for t0, _ in by["repro.chunk_dispatch"])
+    # the device work of the solve runs inside its span
+    inside = [iv for iv in ops if s0 <= iv[0] and iv[1] <= s1]
+    assert inside, "no XLA:CPU op inside repro.solve"
+
+
+@pytest.mark.parametrize("strategy", ["esrp", "imcr"])
+def test_event_report_counters_match_the_spans(problem, strategy):
+    rep = solve_resilient(problem, **dict(FAILSTOP, strategy=strategy),
+                          obs=True)
+    (er,) = rep.events
+    (ev,) = [n for n in walk_spans(span_tree(rep.trace.events))
+             if n["name"] == "event:fail-stop"]
+    line8 = [n for n in walk_spans(ev["children"])
+             if n["name"] == "alg2_line8_aff_solve"]
+    if strategy == "esrp":
+        (l8,) = line8
+        assert er.inner_iters == l8["args"]["inner_iters"] > 0
+    else:
+        assert line8 == [] and er.inner_iters == 0
+    assert er.event_s >= er.recovery_s > 0
+    # event_s runs on past the event span, to the end of the resume step
+    assert er.event_s > ev["dur_us"] / 1e6
+    assert rep.to_json()["events"][0]["inner_iters"] == er.inner_iters
+
+
+def test_runtime_is_the_solve_span(problem, monkeypatch):
+    """runtime_s is the duration of the ``solve`` span, which closes after
+    the final iterate is on the device; the prologue is a part of it."""
+    waited = {}
+    real = jax.block_until_ready
+
+    def block(x):
+        out = real(x)
+        waited[id(x)] = time.perf_counter()
+        return out
+
+    monkeypatch.setattr(jax, "block_until_ready", block)
+    tr = Tracer("t")
+    rep = solve_resilient(problem, **FAILSTOP, obs=tr)
+    (root,) = span_tree(tr.events)
+    assert root["name"] == "solve"
+    assert rep.runtime_s == pytest.approx(root["dur_us"] / 1e6, abs=1e-9)
+    solve_end = tr._clock0 + (root["ts"] + root["dur_us"]) / 1e6
+    assert waited[id(rep.x)] <= solve_end
+    assert [c["name"] for c in root["children"]][-1] == "epilogue"
+    (pro,) = [c for c in root["children"] if c["name"] == "prologue"]
+    assert rep.prologue_s == pytest.approx(pro["dur_us"] / 1e6, abs=1e-9)
+    assert 0 < rep.prologue_s < rep.runtime_s
+    off = solve_resilient(problem, **FAILSTOP)
+    assert 0 < off.prologue_s < off.runtime_s
+
+
+def test_span_without_a_tracer_times_and_records_nothing():
+    with span("phase", "solver", None, big=list(range(3))) as sp:
+        assert sp.t1_us is None
+    assert sp.dur_s >= 0 and sp.args == {"big": [0, 1, 2]}
+    tr = Tracer("t")
+    with span("phase", "solver", tr, n=1) as sp:
+        pass
+    assert tr.current is None
+    (node,) = span_tree(tr.events)
+    assert node["name"] == "phase" and node["args"] == {"n": 1}
+    assert sp.dur_s == pytest.approx(node["dur_us"] / 1e6)
 
 
 def test_export_round_trip(traced, tmp_path):
