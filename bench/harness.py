@@ -373,6 +373,7 @@ def run(args, root: Path, require_chip: bool) -> dict:
     stats = jax.devices()[0].memory_stats() or {}
     dev["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
     geometry = {"m": problem.m, "nnz": op.nnz(cfg),
+                "nnz_upper": op.nnz_upper(cfg), "symmetric": op.SYMMETRIC,
                 "block": int(cfg["precond_block"]),
                 "itemsize": np.dtype(cfg["dtype"]).itemsize}
     del problem

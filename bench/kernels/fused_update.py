@@ -7,8 +7,9 @@ block. Elementwise work is a few flops per 4-byte element, so the kernel is
 bandwidth-bound and its roofline is bytes over the chip's HBM bandwidth.
 """
 BOUND = "bytes"
-# the custom call that the jitted ``fused_pcg_update_batched`` lowers to
-NAME = r"^%fused_pcg_update_batched(\.\d+)?$"
+# the custom call of the ``pallas_call`` named ``fused_pcg_update``, or,
+# without a name, of the jitted ``fused_pcg_update_batched`` around it
+NAME = r"^%fused_pcg_update(_batched)?(\.\d+)?$"
 
 
 def required_bytes(g: dict) -> float:

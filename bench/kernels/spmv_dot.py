@@ -1,17 +1,24 @@
 """SpMV fused with the dot product: q = A p and p·q in one pass
-(the program's ``kernels/spmv``, ``spmv_dot_batched``).
+(the program's ``kernels/spmv``).
 
-Required bytes, counted from the operator and not from any storage format:
-every stored nonzero once as a 4-byte value and a 4-byte int32 column
-index, one read of p and one write of q. The kernel is bandwidth-bound
-(2 flops per nonzero against 8 bytes), so its roofline is bytes over the
-chip's HBM bandwidth.
+Required bytes: the least that any implementation reading the assembled
+operator must move, whatever its storage. Every stored value that A has to
+give at least once, one read of p and one write of q. For a symmetric A
+that is the values on or above the diagonal (``nnz_upper``): the rest are
+the same numbers again. No index or other structure bytes count, since
+storage by diagonals keeps one offset per diagonal and none per value. A
+matrix-free stencil, which reads no values at all, is outside this count
+and would need one of its own. The kernel is bandwidth-bound (2 flops per
+nonzero against at least 4 bytes), so its roofline is bytes over the chip's
+HBM bandwidth.
 """
 BOUND = "bytes"
 # the kernel's HLO instruction in the profiler trace: the custom call that
-# the jitted ``spmv_dot_batched`` lowers to (``pallas_call`` sets no name)
-NAME = r"^%spmv_dot_batched(\.\d+)?$"
+# the ``pallas_call`` named ``spmv_dot`` lowers to, or, without a name, the
+# jitted ``spmv_dot_batched`` around it. Never ``%spmv`` (no dot) alone.
+NAME = r"^%spmv_dot(_batched)?(\.\d+)?$"
 
 
 def required_bytes(g: dict) -> float:
-    return g["nnz"] * (g["itemsize"] + 4) + 2 * g["m"] * g["itemsize"]
+    values = g["nnz_upper"] if g["symmetric"] else g["nnz"]
+    return (values + 2 * g["m"]) * g["itemsize"]

@@ -6,6 +6,10 @@ from __future__ import annotations
 
 import numpy as np
 
+# A equals its transpose: each coupling is stored at (i, j) and at (j, i)
+# with the same value
+SYMMETRIC = True
+
 
 def dims(cfg: dict) -> tuple[int, int, int]:
     nx = int(cfg["nx"])
@@ -17,13 +21,22 @@ def size(cfg: dict) -> int:
     return nx * ny * nz
 
 
+def _pairs(cfg: dict) -> int:
+    """Neighbour pairs of the grid, in x, y and z."""
+    nx, ny, nz = dims(cfg)
+    return (nx - 1) * ny * nz + nx * (ny - 1) * nz + nx * ny * (nz - 1)
+
+
 def nnz(cfg: dict) -> int:
     """Stored nonzeros: every point, plus one coupling per neighbour pair in
     each direction, counted from both ends."""
-    nx, ny, nz = dims(cfg)
-    n = nx * ny * nz
-    pairs = (nx - 1) * ny * nz + nx * (ny - 1) * nz + nx * ny * (nz - 1)
-    return n + 2 * pairs
+    return size(cfg) + 2 * _pairs(cfg)
+
+
+def nnz_upper(cfg: dict) -> int:
+    """Stored nonzeros on or above the diagonal: every point, plus each
+    neighbour pair once."""
+    return size(cfg) + _pairs(cfg)
 
 
 def csr(cfg: dict):

@@ -5,7 +5,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from bench import trace
+from bench import spec, trace
+
+KERNELS = {k: spec.load_module(spec.ROOT / f"bench/kernels/{k}.py")
+           for k in ("spmv_dot", "fused_update")}
 
 
 def test_union_and_gaps():
@@ -25,6 +28,25 @@ def test_op_seconds_and_matching():
     assert trace.matching(table, r"^_spmv_dot") == pytest.approx((1.5, 2))
     assert trace.matching(table, r"^nothing") == (0, 0)
     assert trace.busy_seconds(ops, 0, 5e9) == pytest.approx(1.6)
+
+
+@pytest.mark.parametrize("kernel,op", [
+    ("spmv_dot", "%spmv_dot.1"), ("spmv_dot", "%spmv_dot_batched.1"),
+    ("spmv_dot", "%spmv_dot"),
+    ("fused_update", "%fused_pcg_update.1"),
+    ("fused_update", "%fused_pcg_update_batched.1")])
+def test_kernel_name_matches_named_and_unnamed_custom_call(kernel, op):
+    """A ``pallas_call`` named for the kernel and the jitted wrapper's
+    name, which the custom call takes without one, are both the kernel."""
+    assert trace.matching({op: (2.0, 3)}, KERNELS[kernel].NAME) == (2.0, 3)
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+@pytest.mark.parametrize("op", ["%spmv_batched.1", "%spmv.1", "%fusion.11",
+                                "%spmv_dot_kernel.1",
+                                "%fused_pcg_update_other.1"])
+def test_kernel_name_leaves_other_ops_out(kernel, op):
+    assert trace.matching({op: (2.0, 3)}, KERNELS[kernel].NAME) == (0, 0)
 
 
 def test_label_takes_the_shortest_covering_host_span():
