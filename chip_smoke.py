@@ -168,9 +168,12 @@ def chunk_runner_is_f32_pallas(problem, ops) -> None:
     args = [a.shape for a in jax.tree.leaves(lowered.args_info)]
     log(f"[lowering] {len(text)} characters, {len(args)} arguments; f64 "
         f"vector types: {f64 or 'none'}; Mosaic kernel calls: {calls}")
-    check(problem.a.data.shape in args,
-          "the Block-ELL matrix is not an argument of the chunk runner")
-    check(len(text) < problem.a.data.nbytes // 100,
+    matrix = ops.operands[0]
+    check(matrix.shape in args,
+          "the operator is not an argument of the chunk runner")
+    # an embedded constant prints as hex, two characters a byte; the
+    # program itself is about 56 KB at 128³ by diagonals
+    check(len(text) < matrix.nbytes,
           "the chunk runner embeds the operator as a constant")
     check(not f64, f"f64 vector ops in the chunk runner: {f64}")
     check(calls >= 2, "the chunk runner holds no Mosaic kernel call")
@@ -259,11 +262,13 @@ def one_chip(nx: int) -> None:
     log(f"[ops] hot-loop bundle backend: {ops.backend}")
     check(ops.backend == BACKEND, "wrong bundle")
     a = problem.a
-    data, idx, dia, pinv = ops.operands
+    *matrix, dia, pinv = ops.operands
     log(f"[problem] poisson3d {nx}^3: M={problem.m} dtype={problem.b.dtype} "
         f"nodes={NODES} tiles {a.bm}x{a.bn} kmax={a.kmax} on "
         f"{a.data.devices()}; bytes on the device: Block-ELL "
-        f"{data.nbytes + idx.nbytes + a.nblk.nbytes}, block-Jacobi "
+        f"{a.data.nbytes + a.idx.nbytes + a.nblk.nbytes}, the hot loop's "
+        f"SpMV ({ops.spmv_format}, {ops.spmv_diagonals} diagonals) "
+        f"{sum(m.nbytes for m in matrix)}, block-Jacobi "
         f"diagonals {dia.nbytes} (the fused update reads these), inverse "
         f"and diagonal blocks {pinv.nbytes + problem.diag_blocks.nbytes}")
     chunk_runner_is_f32_pallas(problem, ops)

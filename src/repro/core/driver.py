@@ -130,6 +130,9 @@ class SolveReport:
     events: list[EventReport] = dataclasses.field(default_factory=list)
     precond_variant: str = ""    # e.g. "node-local ssor" on the sharded
     #                              runtime (SolverOps.variant)
+    spmv_format: str = ""        # the SpMV's storage (SolverOps.spmv_format):
+    #                              "dia" | "blockell"; "" for closure ops
+    spmv_diagonals: int = 0      # diagonals it stores ("dia" only)
     local_delta_iters: Optional[int] = None   # iteration-count delta of a
     #                              node-local run vs the global-sweep
     #                              reference (shard.attach_local_delta)
@@ -454,7 +457,9 @@ def solve_resilient(
             solve_sp.args.update(
                 strategy=strategy, T=T, phi=phi,
                 backend=backend, variant=getattr(ops, "variant", ""),
-                tier=tier.name, m=part.m, n_nodes=part.n_nodes, rtol=rtol,
+                spmv_format=ops.spmv_format,
+                spmv_diagonals=ops.spmv_diagonals, tier=tier.name, m=part.m,
+                n_nodes=part.n_nodes, rtol=rtol,
                 aspmv_natural_bytes=nat0, aspmv_total_bytes=tot0,
                 per_push_bytes=per_push)
 
@@ -989,6 +994,7 @@ def solve_resilient(
             aspmv_total_bytes=tot_bytes, run_calls=run_calls,
             events=event_reports,
             precond_variant=getattr(ops, "variant", ""),
+            spmv_format=ops.spmv_format, spmv_diagonals=ops.spmv_diagonals,
             precond_reload_bytes=sum(e.precond_reload_bytes
                                      for e in event_reports),
             tier=tier.name, push_count=push_count,

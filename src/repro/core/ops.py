@@ -6,7 +6,7 @@ hardware allows; constant-factor slack in the hot loop gets misread as
 resilience overhead. ``SolverOps`` bundles the four operations one PCG
 iteration needs —
 
-  * ``matvec``      q = A·p                       (Block-ELL SpMV)
+  * ``matvec``      q = A·p                       (the SpMV)
   * ``matvec_dot``  (q, pᵀq) in one pass          (α needs no 2nd read of p/q)
   * ``precond``     z = P r                       (block-Jacobi apply)
   * ``update``      (x', r', z', rz') fused       (Alg. 1 lines 4-7, one pass)
@@ -26,7 +26,7 @@ decides how each op executes:
                   ``make_closure_ops``; numerics identical to the seed path.
 
 A bundle is a pytree. Its leaves are ``operands``, the arrays its ops read
-(the Block-ELL matrix, the preconditioner); ``bind`` rebuilds the ops over
+(the matrix's storage, the preconditioner); ``bind`` rebuilds the ops over
 whatever leaves it is given. The jitted chunk runners take the bundle as an
 ordinary argument, so the operands reach the compiled program as arguments
 and the ops are rebound to their tracers. Ops that closed over the arrays
@@ -64,6 +64,9 @@ class SolverOps(NamedTuple):
     operands: tuple = ()        # arrays the ops read: the pytree's leaves
     bind: Callable | None = None  # operands -> bundle whose ops read them;
     #                               None = a closure bundle, no operands
+    spmv_format: str = ""       # the SpMV's storage: "dia" | "blockell";
+    #                             "" for a closure bundle
+    spmv_diagonals: int = 0     # diagonals stored ("dia" only)
 
 
 def bound(bind: Callable, operands) -> SolverOps:
@@ -163,7 +166,8 @@ def _per_member(ops: SolverOps, nbatch: int) -> SolverOps:
         return jnp.stack([member_dot(u[i], v[i]) for i in range(nbatch)])
 
     return SolverOps(ops.backend, matvec, matvec_dot, precond, update,
-                     ops.variant, dot)
+                     ops.variant, dot, spmv_format=ops.spmv_format,
+                     spmv_diagonals=ops.spmv_diagonals)
 
 
 def jacobi_apply(pinv_blocks, r):
@@ -179,8 +183,12 @@ def jacobi_apply(pinv_blocks, r):
 
 def make_problem_ops(problem, backend: str, batch: int = 0,
                      fused: bool = False) -> SolverOps:
-    """SolverOps over a ``Problem``'s Block-ELL matrix and its registered
+    """SolverOps over a ``Problem``'s operator and its registered
     preconditioner. backend: "jnp" | "pallas" | "interpret".
+
+    The SpMV runs on the storage ``problem_spmv`` picks from the operator:
+    by diagonals (``kernels/spmv/dia``) when it has few, else Block-ELL
+    (``kernels/spmv/spmv``).
 
     Block-Jacobi (the default) keeps the fully fused update path — the
     preconditioner apply happens *inside* ``kernels/fused_pcg`` while r' is
@@ -198,72 +206,152 @@ def make_problem_ops(problem, backend: str, batch: int = 0,
     to the generic per-member wrapper ``batch_ops``.
 
     ``fused=True`` (batched Jacobi only) swaps the jnp hot-loop ops for the
-    fused-batched einsum variants — one op serves all B members, which is
+    fused-batched variants — one op serves all B members, which is
     what amortizes the batch on an op-overhead-bound host backend — at the
     price of per-member rounding no longer being bit-identical to the B=1
     run (~ulp deviation; convergence unaffected). The serving path opts in;
     the default stays exact.
 
-    The bundle's operands are the matrix's data and column indices, and
-    for block-Jacobi its diagonals (``jacobi_diagonals``, what the fused
-    update reads) and inverse blocks (the standalone precond). A
-    non-Jacobi preconditioner's apply keeps its own arrays."""
+    The bundle's operands are the matrix's (the diagonals, or Block-ELL's
+    data and column indices), and for block-Jacobi its diagonals
+    (``jacobi_diagonals``, what the fused update reads) and inverse blocks
+    (the standalone precond). A non-Jacobi preconditioner's apply keeps its
+    own arrays."""
     from repro.kernels.fused_pcg.fused_pcg import (jacobi_diagonals,
                                                    lanes_for, update_rows)
 
     if backend not in ("jnp", "pallas", "interpret"):
         raise ValueError(f"unknown SolverOps backend {backend!r}")
-    a = problem.a
+    layout, mat = problem_spmv(problem, backend)
     jacobi = problem.precond is None or problem.precond.name == "jacobi"
     if not jacobi:
-        base = bound(_ProblemOps(backend, problem.precond.make_apply(backend)),
-                     (a.data, a.idx))
+        base = bound(_ProblemOps(backend, layout,
+                                 problem.precond.make_apply(backend)), mat)
         # non-jacobi batched bundles stay per-member-unrolled even under
         # fused: the sweep/polynomial applies have no fused-batched form
         return batch_ops(base, batch) if batch else base
     rows = update_rows(problem.m, problem.precond_block)
     dia = jacobi_diagonals(problem.pinv_blocks,
                            lanes_for(rows, problem.precond_block))
-    return bound(_ProblemOps(backend, rows=rows, batch=batch, fused=fused),
-                 (a.data, a.idx, dia, problem.pinv_blocks))
+    return bound(_ProblemOps(backend, layout, rows=rows, batch=batch,
+                             fused=fused),
+                 (*mat, dia, problem.pinv_blocks))
+
+
+def problem_spmv(problem, backend: str) -> tuple:
+    """(layout, arrays) of the problem's SpMV storage for ``backend``:
+    the diagonal kernel's ``DiaLayout`` and the diagonals, or None and
+    Block-ELL's data and column indices. Chosen from the operator and
+    cached on the problem. Diagonals when both hold: they store fewer
+    values than the Block-ELL tiles (n_diag · M < rt · kmax · bm · bn), and
+    the diagonal kernel has a layout for the offsets (few enough of them,
+    an x window that fits VMEM and, compiled for the chip, blocks that
+    Mosaic takes); else Block-ELL."""
+    mosaic = backend == "pallas"
+    cache = getattr(problem, "_spmv_cache", None)
+    if cache is None:
+        cache = problem._spmv_cache = {}
+    if mosaic not in cache:
+        cache[mosaic] = _choose_spmv(problem, mosaic, cache)
+    return cache[mosaic]
+
+
+def _choose_spmv(problem, mosaic: bool, cache: dict) -> tuple:
+    import numpy as np
+
+    from repro.kernels.spmv.dia import dia_layout
+    from repro.sparse.dia import Diagonals, dia_lanes, offsets_of
+
+    a, m = problem.a, problem.m
+    rows, cols, vals = problem.coo
+    offsets = offsets_of(rows, cols, m)
+    lanes = dia_lanes(m)
+    layout = dia_layout(m // lanes, lanes, offsets,
+                        np.dtype(a.dtype).itemsize, mosaic=mosaic)
+    if layout is None or offsets.size * m >= a.data.size:
+        return None, (a.data, a.idx)
+    if "diagonals" not in cache:     # one copy for every backend's layout
+        cache["diagonals"] = Diagonals.from_coo(rows, cols, vals, m,
+                                                a.dtype).data
+    return layout, (cache["diagonals"],)
 
 
 @dataclasses.dataclass(frozen=True)
 class _ProblemOps:
-    """``bind`` of the problem bundles: the ops of ``backend`` over
-    (data, idx[, dia, pinv]). ``precond`` is a non-Jacobi apply; without
-    it the bundle is block-Jacobi with ``rows`` elements per update step."""
+    """``bind`` of the problem bundles: the ops of ``backend`` over the
+    matrix's operands (the diagonals at ``layout``, or with ``layout``
+    None Block-ELL's data and indices) then, for block-Jacobi, (dia, pinv).
+    ``precond`` is a non-Jacobi apply; without it the bundle is
+    block-Jacobi with ``rows`` elements per update step."""
     backend: str
+    layout: object = None       # kernels.spmv.dia.DiaLayout
     precond: Callable | None = None
     rows: int = 0
     batch: int = 0
     fused: bool = False
 
     def __call__(self, operands):
+        n = 2 if self.layout is None else 1
+        mat, rest = tuple(operands[:n]), operands[n:]
         if self.precond is not None:
-            return _swept_ops(self.backend, self.precond, *operands)
-        if self.batch:
-            return _batched_jacobi_ops(self, *operands)
-        return _jacobi_ops(self.backend, self.rows, *operands)
+            ops = _swept_ops(self.backend, self.layout, self.precond, mat)
+        elif self.batch:
+            ops = _batched_jacobi_ops(self, mat, *rest)
+        else:
+            ops = _jacobi_ops(self.backend, self.layout, self.rows, mat,
+                              *rest)
+        if self.layout is None:
+            return ops._replace(spmv_format="blockell")
+        return ops._replace(spmv_format="dia",
+                            spmv_diagonals=len(self.layout.offsets))
 
 
-def _spmv_pair(backend: str):
-    """(matvec, matvec_dot) over (data, idx, x) for one backend."""
-    from repro.kernels.spmv.ref import spmv_dot_ref, spmv_seq_ref
-    from repro.kernels.spmv.spmv import spmv, spmv_dot
+def _spmv_pair(backend: str, layout, mat: tuple, batch: int = 0,
+               fused: bool = False):
+    """(matvec, matvec_dot) over the matrix operands ``mat`` (by diagonals
+    at ``layout``, or Block-ELL where it is None), of one vector or, with
+    ``batch``, of a (B, M) batch: one kernel call for all members, or on the
+    jnp backend the per-member-unrolled refs (exact) or, ``fused``, the
+    whole-batch refs (~ulp)."""
+    from repro.kernels.spmv import dia, ref
+    from repro.kernels.spmv import spmv as block_ell
 
     if backend == "jnp":
-        return spmv_seq_ref, spmv_dot_ref
-    interp = backend == "interpret"
-    return (lambda d, i, x: spmv(d, i, x, interpret=interp),
-            lambda d, i, x: spmv_dot(d, i, x, interpret=interp))
+        mode = "fused" if fused else "batched" if batch else "one"
+        kw = {}
+        pairs = {
+            (True, "one"): (ref.spmv_dia_ref, ref.spmv_dia_dot_ref),
+            (True, "batched"): (ref.spmv_dia_ref_batched,
+                                ref.spmv_dia_dot_ref_batched),
+            (True, "fused"): (ref.spmv_dia_ref, ref.spmv_dia_dot_ref_fused),
+            (False, "one"): (ref.spmv_seq_ref, ref.spmv_dot_ref),
+            (False, "batched"): (ref.spmv_seq_ref_batched,
+                                 ref.spmv_dot_ref_batched),
+            (False, "fused"): (ref.spmv_seq_ref_fused,
+                               ref.spmv_dot_ref_fused),
+        }
+    else:
+        mode = "batched" if batch else "one"
+        kw = {"interpret": backend == "interpret"}
+        pairs = {
+            (True, "one"): (dia.spmv_dia, dia.spmv_dia_dot),
+            (True, "batched"): (dia.spmv_dia_batched,
+                                dia.spmv_dia_dot_batched),
+            (False, "one"): (block_ell.spmv, block_ell.spmv_dot),
+            (False, "batched"): (block_ell.spmv_batched,
+                                 block_ell.spmv_dot_batched),
+        }
+    if layout is not None:
+        kw["layout"] = layout
+    mv, mv_dot = pairs[layout is not None, mode]
+    return lambda x: mv(*mat, x, **kw), lambda x: mv_dot(*mat, x, **kw)
 
 
-def _jacobi_ops(backend, rows, data, idx, dia, pinv) -> SolverOps:
+def _jacobi_ops(backend, layout, rows, mat, dia, pinv) -> SolverOps:
     from repro.kernels.fused_pcg.fused_pcg import fused_pcg_update
     from repro.kernels.fused_pcg.ref import fused_pcg_update_seq_ref
 
-    mv, mv_dot = _spmv_pair(backend)
+    mv, mv_dot = _spmv_pair(backend, layout, mat)
     if backend == "jnp":
         def update(alpha, x, r, p, q):
             return fused_pcg_update_seq_ref(alpha, x, r, p, q, dia, rows)
@@ -277,14 +365,13 @@ def _jacobi_ops(backend, rows, data, idx, dia, pinv) -> SolverOps:
     # seed behaviour: the bundle's standalone precond is the jnp einsum for
     # every backend (used only off the hot path: esrp_init, residual
     # replacement) — keeps cross-backend trajectories bit-identical.
-    return SolverOps(backend, lambda x: mv(data, idx, x),
-                     lambda x: mv_dot(data, idx, x),
-                     lambda r: jacobi_apply(pinv, r), update)
+    return SolverOps(backend, mv, mv_dot, lambda r: jacobi_apply(pinv, r),
+                     update)
 
 
-def _swept_ops(backend, precond, data, idx) -> SolverOps:
+def _swept_ops(backend, layout, precond, mat) -> SolverOps:
     """A non-Jacobi preconditioner: unfused update around its apply."""
-    mv, mv_dot = _spmv_pair(backend)
+    mv, mv_dot = _spmv_pair(backend, layout, mat)
 
     def update(alpha, x, r, p, q):
         x_new = x + alpha * p
@@ -296,62 +383,40 @@ def _swept_ops(backend, precond, data, idx) -> SolverOps:
         z_new = jax.lax.optimization_barrier(precond(r_new))
         return x_new, r_new, z_new, r_new @ z_new
 
-    return SolverOps(backend, lambda x: mv(data, idx, x),
-                     lambda x: mv_dot(data, idx, x), precond, update)
+    return SolverOps(backend, mv, mv_dot, precond, update)
 
 
-def _batched_jacobi_ops(cfg: _ProblemOps, data, idx, dia, pinv
-                        ) -> SolverOps:
+def _batched_jacobi_ops(cfg: _ProblemOps, mat, dia, pinv) -> SolverOps:
     """Batched Jacobi bundle: hot-loop ops are single batched kernel calls
     (one dispatch for B members); the off-hot-loop precond/dot are
     per-member unrolled so every op keeps the per-member f64 bit-identity
     with the unbatched backend.
 
-    ``fused=True`` routes the jnp hot loop through the fused-batched einsum
-    refs instead (one op per iteration for the whole batch — the
-    throughput mode; see kernels/spmv/ref.py) and batches precond/dot the
-    same way. The Pallas/interpret kernels are already one dispatch per
-    batch either way."""
+    ``fused=True`` routes the jnp hot loop through the fused-batched refs
+    instead (one op per iteration for the whole batch — the throughput
+    mode; see kernels/spmv/ref.py) and batches precond/dot the same way.
+    The Pallas/interpret kernels are already one dispatch per batch either
+    way."""
     import jax.numpy as jnp
 
     from repro.kernels.fused_pcg.fused_pcg import fused_pcg_update_batched
     from repro.kernels.fused_pcg.ref import (fused_pcg_update_ref_fused,
                                              fused_pcg_update_seq_ref_batched)
-    from repro.kernels.spmv.ref import (spmv_dot_ref_batched,
-                                        spmv_dot_ref_fused,
-                                        spmv_seq_ref_batched,
-                                        spmv_seq_ref_fused)
-    from repro.kernels.spmv.spmv import spmv_batched, spmv_dot_batched
 
     backend, batch, rows = cfg.backend, cfg.batch, cfg.rows
-    if backend == "jnp" and cfg.fused:
-        def matvec(x):
-            return spmv_seq_ref_fused(data, idx, x)
-
-        def matvec_dot(x):
-            return spmv_dot_ref_fused(data, idx, x)
-
+    fused = cfg.fused and backend == "jnp"
+    matvec, matvec_dot = _spmv_pair(backend, cfg.layout, mat, batch,
+                                    fused)
+    if fused:
         def update(alpha, x, r, p, q):
             return fused_pcg_update_ref_fused(alpha, x, r, p, q, pinv,
                                               rows=rows)
     elif backend == "jnp":
-        def matvec(x):
-            return spmv_seq_ref_batched(data, idx, x)
-
-        def matvec_dot(x):
-            return spmv_dot_ref_batched(data, idx, x)
-
         def update(alpha, x, r, p, q):
             return fused_pcg_update_seq_ref_batched(alpha, x, r, p, q, dia,
                                                     rows)
     else:
         interp = backend == "interpret"
-
-        def matvec(x):
-            return spmv_batched(data, idx, x, interpret=interp)
-
-        def matvec_dot(x):
-            return spmv_dot_batched(data, idx, x, interpret=interp)
 
         def update(alpha, x, r, p, q):
             return fused_pcg_update_batched(alpha, x, r, p, q, dia,

@@ -9,6 +9,8 @@ exactly across the jnp and Pallas ``SolverOps`` backends.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -122,3 +124,82 @@ def spmv_dot_ref_fused(data: jax.Array, idx: jax.Array,
     partial = jnp.sum(acc * x.reshape(nb, rt, bm), axis=2)       # (B, rt)
     partial = jax.lax.optimization_barrier(partial)
     return acc.reshape(nb, rt * bm), jnp.sum(partial, axis=1)
+
+
+# --------------------------------------------------------------------------- #
+# storage by diagonals (kernels/spmv/dia.py): the kernel's terms over the
+# whole vector. x[g + d] is a slice of the zero-padded x, the same values
+# the kernel's roll-and-select gives; the terms are added in d order, the
+# first two in one expression, as in the kernel; the dot sums the products
+# per lane row, then over the rows, as the kernel's caller does. So the jnp
+# and interpret backends agree bit for bit in f64.
+# --------------------------------------------------------------------------- #
+def dia_terms(data: jax.Array, offsets, x: jax.Array):
+    """(n_diag, R, L) diagonals at ``offsets``, x: (..., M) -> y =
+    Σ_d D_d · x[g + d] and the dot's products x⊙y, each (..., R, L).
+
+    The sum runs in a loop over the diagonals, as the kernel's runs in its
+    grid loop, and the products are formed in it: both leave the loop
+    materialized, as they leave the kernel, so XLA:CPU cannot fuse a
+    multiply into an add downstream and contract it differently there."""
+    nd, n_rows, lanes = data.shape
+    m = n_rows * lanes
+    pad = max(abs(d) for d in offsets)
+    xp = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(pad, pad)])
+    starts = jnp.asarray(offsets, jnp.int32) + pad
+    shape = (*x.shape[:-1], n_rows, lanes)
+    xv = x.reshape(shape)
+
+    def product(k):
+        xd = jax.lax.dynamic_slice_in_dim(xp, starts[k], m, axis=-1)
+        return data[k] * xd.reshape(shape)
+
+    def term(k, carry):
+        y = carry[0] + product(k)
+        return y, y * xv
+
+    # the first two terms in one expression, as the kernel adds them: an
+    # add of two products, which XLA:CPU contracts with the first
+    y = product(0) if nd == 1 else product(0) + product(1)
+    return jax.lax.fori_loop(min(nd, 2), nd, term, (y, y * xv))
+
+
+@functools.partial(jax.jit, static_argnames=("layout",))
+def spmv_dia_ref(data: jax.Array, x: jax.Array, *, layout) -> jax.Array:
+    """y = A @ x with the diagonal kernel's arithmetic; x: (M,), or (B, M)
+    for the whole batch in one pass."""
+    return dia_terms(data, layout.offsets, x)[0].reshape(x.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("layout",))
+def spmv_dia_dot_ref(data: jax.Array, x: jax.Array, *, layout
+                     ) -> tuple[jax.Array, jax.Array]:
+    """(A @ x, xᵀAx) for one x, mirroring ``spmv_dia_dot``: the products
+    reduced per lane row, then over the rows."""
+    from repro.kernels.spmv.dia import reduce_products
+
+    y, prod = dia_terms(data, layout.offsets, x)
+    return y.reshape(x.shape), reduce_products(prod)
+
+
+def spmv_dia_ref_batched(data, x, *, layout):
+    """x: (B, M); member i identical to ``spmv_dia_ref(x[i])``."""
+    return jnp.stack([spmv_dia_ref(data, x[i], layout=layout)
+                      for i in range(x.shape[0])])
+
+
+def spmv_dia_dot_ref_batched(data, x, *, layout):
+    """Batched (A @ x, xᵀAx), member i identical to its B = 1 call."""
+    pairs = [spmv_dia_dot_ref(data, x[i], layout=layout)
+             for i in range(x.shape[0])]
+    return (jnp.stack([y for y, _ in pairs]),
+            jnp.stack([d for _, d in pairs]))
+
+
+@functools.partial(jax.jit, static_argnames=("layout",))
+def spmv_dia_dot_ref_fused(data, x, *, layout):
+    """Batched (A @ x, xᵀAx) in one pass for the whole batch; the dot's
+    sums run over all members at once (~ulp, not bit-identical)."""
+    y, prod = dia_terms(data, layout.offsets, x)
+    partial = jax.lax.optimization_barrier(jnp.sum(prod, axis=2))
+    return y.reshape(x.shape), jnp.sum(partial, axis=1)

@@ -1,4 +1,6 @@
-"""Block-ELL SpMV Pallas TPU kernel — the paper's per-iteration hot spot.
+"""Block-ELL SpMV Pallas TPU kernel — the hot loop's SpMV for operators
+with many or scattered diagonals (``core.ops.problem_spmv`` gives stencils
+the diagonal kernel, ``kernels/spmv/dia.py``).
 
 TPU adaptation of the paper's CSR SpMV (GSL on CPU): the matrix is stored as
 dense (bm x bn) tiles with per-slot column-tile indices. The key TPU
@@ -113,6 +115,7 @@ def _call(kernel, data, idx, x, n_out: int, with_rows: bool, interpret):
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((nb, rt, 1, bm), data.dtype),) * n_out,
         interpret=interpret,
+        name="spmv_dot" if with_rows else "spmv",
     )(idx.reshape(-1), data, *args)
     return [o.reshape(nb, rt, bm) for o in outs]
 

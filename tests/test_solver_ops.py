@@ -32,6 +32,9 @@ def problems():
     return {
         "poisson2d": build_problem("poisson2d", n_nodes=4, nx=16, ny=16),
         "poisson3d": build_problem("poisson3d", n_nodes=4, nx=8),
+        "anisotropic": build_problem("poisson3d", n_nodes=4, nx=8, eps=0.1),
+        # 41 diagonals: more than the diagonal kernel takes, so Block-ELL
+        "banded": build_problem("banded", n_nodes=4, n=320, bandwidth=20),
     }
 
 
@@ -59,6 +62,24 @@ def test_trajectory_bit_identical_across_backends(problems, kind, T, n_iters):
     s_p, norms_p = esrp.run_chunk(s_p, ops_pal, T, n_iters, thresh)
     np.testing.assert_array_equal(np.asarray(norms_j), np.asarray(norms_p))
     _assert_tree_equal(s_j, s_p)
+
+
+@pytest.mark.parametrize("kind,fmt", [("poisson3d", "dia"),
+                                      ("anisotropic", "dia"),
+                                      ("banded", "blockell")])
+def test_trajectory_bit_identical_across_backends_per_format(problems, kind,
+                                                              fmt):
+    """Both SpMV storages: the interpret kernels and the jnp mirrors walk
+    the same f64 trajectory."""
+    p = problems[kind]
+    thresh = jnp.asarray(0.0, p.b.dtype)
+    out = []
+    for backend in ("jnp", "interpret"):
+        ops = p.solver_ops(backend)
+        assert ops.spmv_format == fmt
+        s = esrp.esrp_init(ops.matvec, ops.precond, p.b)
+        out.append(esrp.run_chunk(s, ops, 5, 25, thresh))
+    _assert_tree_equal(*out)
 
 
 def test_failure_recovery_bit_identical_across_backends(problems):
@@ -137,32 +158,50 @@ def _dots(jaxpr):
     return walker.count_primitives(jaxpr, "dot_general", into_conds=False)
 
 
+# what one SpMV runs, in either storage: a dot and a gather per Block-ELL
+# slot, or by diagonals a loop of slices
+_SPMV_PRIMITIVES = ("dot_general", "gather", "dynamic_slice", "scan", "while")
+
+
 def test_cond_gates_storage_and_replacement(problems):
     """gated=True must hoist the queue rotation, star capture, and the
     residual-replacement SpMV+precond into lax.cond branches: no extra
     SpMV/dot executes on non-replacement iterations. gated=False (the seed
-    path) keeps them inline in the main trace."""
-    p = problems["poisson2d"]
-    ops = p.solver_ops("jnp")
-    s0 = esrp.esrp_init(ops.matvec, ops.precond, p.b)
+    path) keeps them inline in the main trace. Checked for both SpMV
+    storages: by diagonals (poisson2d) and Block-ELL (banded)."""
+    for kind, fmt in (("poisson2d", "dia"), ("banded", "blockell")):
+        p = problems[kind]
+        ops = p.solver_ops("jnp")
+        assert ops.spmv_format == fmt
+        s0 = esrp.esrp_init(ops.matvec, ops.precond, p.b)
 
-    def step(gated):
-        return jax.make_jaxpr(
-            lambda s: esrp.esrp_step(s, ops, 20, b=p.b, rr_every=10,
-                                     gated=gated))(s0).jaxpr
+        def step(gated):
+            return jax.make_jaxpr(
+                lambda s: esrp.esrp_step(s, ops, 20, b=p.b, rr_every=10,
+                                         gated=gated))(s0).jaxpr
 
-    gated, ungated = step(True), step(False)
-    conds = [e for e in gated.eqns if e.primitive.name == "cond"]
-    assert len(conds) >= 3          # queue push, star capture, replacement
-    # inline (unconditionally executed) dots: gated must have strictly fewer
-    # — the replacement SpMV (kmax dots) + precond dot moved under cond
-    top_gated = _dots(gated)
-    top_ungated = _dots(ungated)
-    assert top_gated < top_ungated, (top_gated, top_ungated)
-    kmax = p.a.kmax
-    # the replacement branch is one SpMV (kmax slot dots) + precond einsum
-    # + the rᵀz dot — all inline when ungated, all under cond when gated
-    assert top_ungated - top_gated == kmax + 2, (top_gated, top_ungated)
+        gated, ungated = step(True), step(False)
+        conds = [e for e in gated.eqns if e.primitive.name == "cond"]
+        assert len(conds) >= 3      # queue push, star capture, replacement
+        # inline (unconditionally executed) dots: gated must have strictly
+        # fewer — the replacement SpMV + precond dot moved under cond
+        top_gated = _dots(gated)
+        top_ungated = _dots(ungated)
+        assert top_gated < top_ungated, (top_gated, top_ungated)
+        # the replacement branch is one SpMV + precond einsum + the rᵀz
+        # dot — all inline when ungated, all under cond when gated
+        spmv = jax.make_jaxpr(ops.matvec)(p.b).jaxpr
+        spmv_dots = _dots(spmv)
+        if fmt == "blockell":
+            assert spmv_dots == p.a.kmax        # one dot per slot
+        assert top_ungated - top_gated == spmv_dots + 2, (
+            fmt, top_gated, top_ungated)
+        # and the SpMV's own work, whatever its storage
+        spmv_work = walker.count_primitives(spmv, _SPMV_PRIMITIVES)
+        assert spmv_work > 0
+        moved = (walker.count_primitives(ungated, _SPMV_PRIMITIVES)
+                 - walker.count_primitives(gated, _SPMV_PRIMITIVES))
+        assert moved == spmv_work + 2, (fmt, moved, spmv_work)
 
 
 def test_gated_trajectory_matches_ungated(problems):
